@@ -8,11 +8,6 @@ Run standalone (it forces 512 host devices):
 Writes benchmarks/perf_ladder.json.
 """
 import os
-
-os.environ["XLA_FLAGS"] = os.environ.get(
-    "REPRO_DRYRUN_XLA_FLAGS", "--xla_force_host_platform_device_count=512"
-)
-
 import dataclasses
 import json
 
@@ -92,4 +87,7 @@ def main():
 
 
 if __name__ == "__main__":
+    os.environ["XLA_FLAGS"] = os.environ.get(
+        "REPRO_DRYRUN_XLA_FLAGS", "--xla_force_host_platform_device_count=512"
+    )
     main()

@@ -1,6 +1,11 @@
 """Paper Table 3 / Fig. 8: scalability with worker count. Workers are
 forced host devices in subprocesses (1, 2, 4, 8); speedup is relative to 1
-worker, like the paper's Fig. 8 normalises to 5 servers."""
+worker, like the paper's Fig. 8 normalises to 5 servers.
+
+A CPU-only bench: every child forces ``JAX_PLATFORMS=cpu`` host devices,
+so its times are CPU times. It refuses to run where JAX's backend is a
+TPU — the parent already holds the chip there, and a child could not
+reach it. A child that fails fails the bench."""
 from __future__ import annotations
 
 import json
@@ -35,9 +40,17 @@ SCRIPT = textwrap.dedent(
 
 
 def main():
+    import jax
+
+    if jax.default_backend() == "tpu":
+        raise RuntimeError(
+            "bench_scalability forces host CPU devices in child processes; "
+            "it is a CPU-only bench and does not run on a TPU backend"
+        )
     times = {}
     for n in (1, 2, 4, 8):
         env = dict(os.environ)
+        env["JAX_PLATFORMS"] = "cpu"
         env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n}"
         env.setdefault("PYTHONPATH", "src")
         proc = subprocess.run(
@@ -45,8 +58,10 @@ def main():
             env=env, capture_output=True, text=True, timeout=1200,
         )
         if proc.returncode != 0:
-            emit(f"table3.motifs_{n}w", -1, "error")
-            continue
+            raise RuntimeError(
+                f"scalability child with {n} workers failed "
+                f"(exit {proc.returncode}):\n{proc.stderr[-3000:]}"
+            )
         line = [l for l in proc.stdout.splitlines() if l.startswith("RESULT")][0]
         out = json.loads(line[len("RESULT"):])
         times[n] = out["time_s"]

@@ -18,6 +18,7 @@ from __future__ import annotations
 
 import argparse
 import json
+import os
 import platform
 import sys
 import traceback
@@ -37,6 +38,10 @@ def main(argv=None) -> None:
         help=f"write emitted rows as JSON (default: {DEFAULT_BENCH_JSON})",
     )
     opts = args.parse_args(argv)
+    from repro.compile_cache import use_compile_cache
+
+    use_compile_cache(os.path.dirname(os.path.dirname(os.path.abspath(
+        __file__))))
     from benchmarks import (
         bench_aggregate,
         bench_breakdown,

@@ -46,7 +46,6 @@ from repro.core.runtime.shard import (  # noqa: F401  (canonical home)
     pad_parts,
     partition_frontier,
     shard_map,
-    shard_map_pallas_ok as _shard_map_pallas_ok,
 )
 __all__ = ["DistConfig", "run_distributed", "mining_step_for_dryrun"]
 
@@ -114,8 +113,7 @@ def mining_step_for_dryrun(mesh: Mesh, axes=("pod", "data"),
             return children[None], count[None], counts[None]
 
         spec = P(axes)
-        mapper = _shard_map_pallas_ok if resolved_pallas else shard_map
-        return mapper(
+        return shard_map(
             functools.partial(worker, g, quick_dict),
             mesh=mesh,
             in_specs=(spec, spec),

@@ -30,6 +30,7 @@ from repro.kernels import aggregate as aggregate_kernel_lib
 from repro.kernels import compact as compact_kernel_lib
 from repro.kernels import gather as gather_kernel_lib
 from repro.kernels.canonical_check import ops as cc_ops
+from repro.kernels.dispatch import note_route
 
 
 class TileView(NamedTuple):
@@ -196,8 +197,10 @@ def expand_vertex(
     (``expand_canonical``), skipping the ``(C, k, k, D)`` HBM intermediate.
     Both fall back to this jnp path when the graph exceeds the VMEM limits.
     """
-    if use_pallas and fused and cc_ops.fits_vmem_fused(g):
-        return _expand_vertex_fused(g, members, n_valid, interpret)
+    if use_pallas and fused:
+        if cc_ops.fits_vmem_fused(g):
+            return _expand_vertex_fused(g, members, n_valid, interpret)
+        note_route("expand_canonical", "jnp:tables-exceed-vmem")
     c, k = members.shape
     d = g.max_degree
     pos = jnp.arange(k)[None, :]
@@ -374,11 +377,9 @@ def compact(
     ``use_kernel`` routes the keep-mask compaction through the Pallas
     stream-compaction kernel (``kernels/compact.py``, DESIGN.md §8)
     instead of the jnp nonzero gather; both honour the same contract.
-    Capacities whose index window exceeds the kernel's VMEM limit fall
-    back to the jnp gather (same rule as the canonical-check bitmap).
     """
     c, k = members.shape
-    if use_kernel and compact_kernel_lib.fits_vmem(out_cap):
+    if use_kernel:
         idx, count = compact_kernel_lib.stream_compact_pallas(
             keep, out_cap, interpret=interpret
         )

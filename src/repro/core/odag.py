@@ -21,8 +21,10 @@ Two representations:
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Callable, List, Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -139,6 +141,41 @@ def merge(odags: List[ODAG]) -> ODAG:
     return ODAG(k=k, domains=domains, conn=conn)
 
 
+@functools.partial(
+    jax.jit, static_argnames=("mode", "use_pallas", "interpret")
+)
+def _extension_mask(g, mem, nv, cnd, *, mode: str, use_pallas: bool,
+                    interpret):
+    """Algorithm-1 filters of one extraction batch (validity + incremental
+    canonicality), one jitted program per batch shape."""
+    distinct = ~(mem == cnd[:, None]).any(axis=1)
+    if mode == "vertex":
+        # validity: adjacency to some member + distinctness
+        attach = g.is_edge(mem, cnd[:, None]).any(axis=1)
+        if use_pallas:
+            canon = cc_ops.canonical_check(
+                g, mem, nv, cnd, mode="vertex", interpret=interpret
+            )
+        else:
+            canon = canonical.vertex_check(g, mem, nv, cnd)
+    else:
+        mu = g.edge_uv[jnp.maximum(mem, 0)]        # (B, k, 2)
+        cu = g.edge_uv[jnp.maximum(cnd, 0)]        # (B, 2)
+        attach = (
+            (mu[..., 0] == cu[:, None, 0])
+            | (mu[..., 0] == cu[:, None, 1])
+            | (mu[..., 1] == cu[:, None, 0])
+            | (mu[..., 1] == cu[:, None, 1])
+        ).any(axis=1)
+        if use_pallas:
+            canon = cc_ops.canonical_check(
+                g, mem, nv, cnd, mode="edge", interpret=interpret
+            )
+        else:
+            canon = canonical.edge_check(g, mem, nv, cnd)
+    return attach & distinct & canon
+
+
 def extract(
     g: DeviceGraph,
     odag: ODAG,
@@ -173,32 +210,11 @@ def extract(
             mem = jnp.asarray(np.repeat(pc, d, axis=0))    # (P*D, lvl+1)
             cnd = jnp.asarray(cand.reshape(-1))
             nv = jnp.full((p * d,), lvl + 1, dtype=jnp.int32)
-            distinct = ~(mem == cnd[:, None]).any(axis=1)
-            if mode == "vertex":
-                # validity: adjacency to some member + distinctness
-                attach = g.is_edge(mem, cnd[:, None]).any(axis=1)
-                if use_pallas:
-                    canon = cc_ops.canonical_check(
-                        g, mem, nv, cnd, mode="vertex", interpret=interpret
-                    )
-                else:
-                    canon = canonical.vertex_check(g, mem, nv, cnd)
-            else:
-                mu = g.edge_uv[jnp.maximum(mem, 0)]        # (B, k, 2)
-                cu = g.edge_uv[jnp.maximum(cnd, 0)]        # (B, 2)
-                attach = (
-                    (mu[..., 0] == cu[:, None, 0])
-                    | (mu[..., 0] == cu[:, None, 1])
-                    | (mu[..., 1] == cu[:, None, 0])
-                    | (mu[..., 1] == cu[:, None, 1])
-                ).any(axis=1)
-                if use_pallas:
-                    canon = cc_ops.canonical_check(
-                        g, mem, nv, cnd, mode="edge", interpret=interpret
-                    )
-                else:
-                    canon = canonical.edge_check(g, mem, nv, cnd)
-            keep = np.asarray(attach & distinct & canon) & mask.reshape(-1)
+            valid = _extension_mask(
+                g, mem, nv, cnd,
+                mode=mode, use_pallas=use_pallas, interpret=interpret,
+            )
+            keep = np.asarray(valid) & mask.reshape(-1)
             if app_filter is not None:
                 keep = keep & np.asarray(app_filter(mem, nv, cnd))
             sel = np.nonzero(keep)[0]

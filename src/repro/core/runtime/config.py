@@ -51,14 +51,14 @@ class RunConfig:
                                   # in the distributed backend)
     max_steps: int = 16           # hard cap on exploration depth
     #: route the Alg.-2 canonicality check through the Pallas kernel
-    #: (VMEM-sized graphs, vertex mode). None -> auto: on for backends with
-    #: a native Pallas lowering (TPU/GPU), off on CPU.
+    #: (VMEM-sized graphs, vertex mode). None -> auto: the cost model
+    #: decides (statically: on for TPU, the one compiled target).
     use_pallas: Optional[bool] = None
     #: with use_pallas, also fuse candidate validity + dedup + Alg.-2 into
     #: the single-pass expand_canonical kernel (vertex mode).
     fused_expand: bool = False
     #: Pallas interpret override; None -> auto per backend (compiled on
-    #: TPU/GPU, interpreter on CPU).
+    #: TPU, interpreter elsewhere; True is refused on TPU).
     pallas_interpret: Optional[bool] = None
     #: how the frontier lives between supersteps: "raw" keeps the dense
     #: embedding list, "odag" stores per-size ODAGs (paper §5.2) and

@@ -85,11 +85,14 @@ COST_MODEL_MODES = ("auto", "off", "force_device", "force_host")
 
 #: pilot rows the expand ladder times (a real size-1 chunk slice).
 PROBE_CHUNK_ROWS = 256
-#: rows the bin ladder times (pilot children tiled up — large enough that
-#: the sort-vs-radix ordering matches full-superstep batches).
-PROBE_BIN_ROWS = 65536
-#: expand-probe output capacity cap (keeps one probe under ~10 ms).
-PROBE_OUT_CAP = 1 << 15
+#: rows the bin ladder times (pilot children tiled up). Every probe
+#: program is compiled before it is timed, and on TPU v5e compile time
+#: grows faster than linearly with array length (a 1-D gather of 131,072
+#: indices takes 44 s to compile, of 8,192 indices 2.6 s), so the probes
+#: stay at a size whose compile the pilot can afford.
+PROBE_BIN_ROWS = 8192
+#: expand-probe output capacity cap (same compile-time bound).
+PROBE_OUT_CAP = 1 << 13
 #: a non-jnp expand combo must be >=10% faster than plain jnp at probe
 #: time to be chosen — near-ties are measurement noise, not wins.
 EXPAND_HYSTERESIS = 0.9
@@ -147,9 +150,8 @@ def static_table(backend_name: str, platform: Optional[str] = None,
                  source: str = "static") -> DecisionTable:
     """The pre-calibration defaults, exactly as the old static heuristic
     resolved them: fused pipeline + device aggregation everywhere, Pallas
-    kernels only where they compile to native code (TPU — the Triton path
-    is unvalidated for the 2-D gathers these kernels lean on), sort-based
-    bin.  Small graphs (below ``cost_model_min_edges``) resolve here so a
+    kernels only where they compile to native code (TPU, the one target
+    their Mosaic-specific operands lower for), sort-based bin.  Small graphs (below ``cost_model_min_edges``) resolve here so a
     unit-test-sized run never pays a calibration pilot."""
     p = platform or _platform()
     native = p == "tpu"
@@ -257,16 +259,10 @@ def _time_us(fn, repeat: int = 3) -> float:
 
 def calibrate(g, app, config, backend_name: str) -> DecisionTable:
     """Run the probe set on a pilot-sized slice of the real workload and
-    return the measured-fastest table.  Any probe failure (exotic graph
-    layout, pathological sizes) falls back to the static table — the cost
-    model must never be able to break a run, only re-place it."""
-    try:
-        return _calibrate(g, app, config, backend_name)
-    except Exception:  # pragma: no cover - safety net, exercised by tests
-        return static_table(backend_name, source="static:probe-error")
-
-
-def _calibrate(g, app, config, backend_name: str) -> DecisionTable:
+    return the measured-fastest table.  A probe that fails (a kernel the
+    device compiler refuses, say) raises: the same program would fail the
+    run, and a table that silently routes around it would hide the fault
+    behind a slower path."""
     from repro.core import explore
     from repro.core.runtime import programs
 

@@ -37,6 +37,7 @@ from repro.core.runtime import programs
 from repro.core.runtime.backend import ExecutionBackend
 from repro.core.runtime.config import RunConfig
 from repro.core.stats import RunStats, StepStats, Timer
+from repro.kernels import dispatch
 
 
 @dataclasses.dataclass
@@ -208,6 +209,10 @@ class SuperstepRuntime:
         #: Dropped across a resume (recomputed from the store, same result).
         carried: Optional[tuple] = None
 
+        #: kernel routes of the programs traced during the run's supersteps
+        #: (the cost model's probes at bind time are not recorded)
+        routes: dict = {}
+        dispatch.push_route_sink(routes)
         try:
             for step in range(first_step, config.max_steps + 1):
                 b = store.n_rows
@@ -407,12 +412,16 @@ class SuperstepRuntime:
                 time.perf_counter() - t_start
             )
             backend.finalize(result.stats)
+            result.stats.kernel_routes = {
+                k: sorted(v) for k, v in sorted(routes.items())
+            }
             self.failed_phase = None
             result.trace_path = observer.finish(
                 wall_time=result.stats.wall_time
             )
             return result
         finally:
+            dispatch.pop_route_sink(routes)
             # exception path: uninstall the tracer/registry so a failed
             # traced run can't leak observation into later runs; exports
             # the partial trace (idempotent after a normal finish), marked

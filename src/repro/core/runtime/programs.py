@@ -99,10 +99,18 @@ def initial_frontier(g: DeviceGraph, mode: str) -> np.ndarray:
     return np.arange(n0, dtype=np.int32)[:, None]
 
 
-def quick_patterns(g: DeviceGraph, mode: str, members, n_valid):
+@functools.partial(jax.jit, static_argnames=("mode",))
+def _quick_patterns(g, members, n_valid, mode: str):
     if mode == "vertex":
         return pattern_lib.quick_pattern_vertex(g, members, n_valid)
     return pattern_lib.quick_pattern_edge(g, members, n_valid)
+
+
+def quick_patterns(g: DeviceGraph, mode: str, members, n_valid):
+    """Quick-pattern codes of a frontier batch, as one jitted program per
+    batch shape (run eagerly, every primitive would compile on its own for
+    each new wave size)."""
+    return _quick_patterns(g, members, n_valid, mode)
 
 
 def device_chunk(wave_dev, lo: int, cb: int, bucket: int, k: int):
@@ -156,8 +164,12 @@ def store_app_filter(app: MiningApp, g: DeviceGraph):
     if type(app).filter is MiningApp.filter:
         return None
 
-    def phi(mem, nv, cnd):
+    @jax.jit
+    def filt(g, mem, nv, cnd):
         rows = jnp.arange(int(mem.shape[0]), dtype=jnp.int32)
         return app.filter(g, mem, nv, rows, cnd)
+
+    def phi(mem, nv, cnd):
+        return filt(g, mem, nv, cnd)
 
     return phi

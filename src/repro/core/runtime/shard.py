@@ -33,7 +33,7 @@ from typing import List, NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import aggregation, explore, obs, pattern as pattern_lib
 from repro.core.api import MiningApp
@@ -48,26 +48,26 @@ from repro.kernels import canonical_refine
 from repro.kernels import gather as gather_kernel_lib
 from repro.kernels.dispatch import device_scope
 
-try:  # jax >= 0.6 exports shard_map at top level
-    shard_map = jax.shard_map
-except AttributeError:  # jax 0.4/0.5: experimental namespace
-    from jax.experimental.shard_map import shard_map
+def shard_map(f, mesh, in_specs, out_specs):
+    """``jax.shard_map`` as every worker program here uses it: with the
+    varying-manual-axes check off, because a worker body may contain a
+    ``pallas_call``, which has no rule for that check."""
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=False,
+    )
 
 
-def shard_map_pallas_ok(f, mesh, in_specs, out_specs):
-    """shard_map with the replication check disabled: pallas_call has no
-    replication rule, so worker bodies that may contain a kernel need
-    check_rep=False (renamed check_vma in newer jax)."""
-    try:
-        return shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=False,
-        )
-    except TypeError:
-        return shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
+def auto_mesh(mesh: Mesh) -> Mesh:
+    """``mesh`` with every axis of type ``Auto``.
+
+    ``jax.make_mesh`` builds ``Explicit`` axes, under which the host-side
+    reads of a program's sharded outputs (``gn[0]``, a replicated table
+    gathered by a sharded slot array) have no unambiguous sharding and
+    raise. The backend's programs shard explicitly through ``shard_map``
+    anyway, so it runs them on the ``Auto`` view of the same devices."""
+    return Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
 
 
 def mesh_axis_size(mesh: Mesh, axes) -> int:
@@ -139,11 +139,8 @@ def make_sharded_expand(app: MiningApp, mesh: Mesh, axes=("data",),
                 outs += (codes[None], lv[None])
             return outs
 
-        mapper = (
-            shard_map_pallas_ok if (use_pallas or compact_kernel) else shard_map
-        )
         n_out = 6 if with_patterns else 4
-        return mapper(
+        return shard_map(
             functools.partial(worker, g),
             mesh=mesh,
             in_specs=(spec_in, spec_in),
@@ -297,11 +294,8 @@ def make_sharded_expand_partitioned(app: MiningApp, mesh: Mesh,
             nbr_sh=spec_in, nbr_eid_sh=spec_in, deg_sh=spec_in,
             adj_sh=spec_in,
         )
-        mapper = (
-            shard_map_pallas_ok if (use_pallas or compact_kernel) else shard_map
-        )
         n_out = 6 if with_patterns else 4
-        return mapper(
+        return shard_map(
             worker,
             mesh=mesh,
             in_specs=(pg_specs, spec_in, spec_in),
@@ -340,8 +334,7 @@ def make_sharded_halo_probe(mode: str, mesh: Mesh, axes=("data",),
             nbr_sh=spec_in, nbr_eid_sh=spec_in, deg_sh=spec_in,
             adj_sh=spec_in,
         )
-        mapper = shard_map_pallas_ok if compact_kernel else shard_map
-        return mapper(
+        return shard_map(
             worker,
             mesh=mesh,
             in_specs=(pg_specs, spec_in, spec_in),
@@ -436,8 +429,7 @@ def make_sharded_quick_bin(mesh: Mesh, axes=("data",), use_kernel=False,
             return (gu[None], counts[None], gn[None], corrupt[None],
                     row_slot[None])
 
-        mapper = shard_map_pallas_ok if use_kernel else shard_map
-        return mapper(
+        return shard_map(
             worker,
             mesh=mesh,
             in_specs=(spec, spec),
@@ -513,11 +505,23 @@ def make_sharded_aggregate(mesh: Mesh, axes=("data",)):
     return agg
 
 
+def place_partitioned(pg: PartitionedGraph, mesh: Mesh,
+                      axes) -> PartitionedGraph:
+    """Put each stacked shard table (``*_sh``, leading axis = shard axis)
+    on its worker's device and replicate the rest over ``mesh``."""
+    shard = NamedSharding(mesh, P(axes))
+    rep = NamedSharding(mesh, P())
+    return PartitionedGraph(*(
+        jax.device_put(x, shard if f.endswith("_sh") else rep)
+        for f, x in zip(pg._fields, pg)
+    ))
+
+
 class ShardMapBackend(ExecutionBackend):
     name = "shard_map"
 
     def __init__(self, mesh: Mesh, axes=None) -> None:
-        self.mesh = mesh
+        self.mesh = auto_mesh(mesh)
         self._axes_override = axes
 
     def _make_store(self) -> FrontierStore:
@@ -576,6 +580,9 @@ class ShardMapBackend(ExecutionBackend):
                     f"shard-map worker count ({self.n_shards}): the "
                     f"in-program halo exchange maps one CSR shard per worker"
                 )
+            # one CSR shard + bitmap tile per worker device, the id/label
+            # payload replicated: placed once, not resharded per program call
+            self.g = place_partitioned(self.g, self.mesh, self.axes)
             self._halo = config.resolve_halo()
             self._expand = make_sharded_expand_partitioned(
                 app, self.mesh, self.axes,

@@ -95,6 +95,13 @@ class RunStats:
     #: "calibrated" / "cached" / "forced:<mode>") — placement decisions
     #: must be observable after the fact, not inferred from timings.
     cost_model: Dict = dataclasses.field(default_factory=dict)
+    #: kernel -> routes traced during this run (``"compiled"`` /
+    #: ``"interpret"`` for a Pallas kernel that ran, ``"jnp:<reason>"``
+    #: for a dispatch that declined its kernel, e.g. a bitmap too large
+    #: for VMEM). Recorded at trace time (``kernels.dispatch.note_route``):
+    #: programs this process traced in an earlier run are reused and add
+    #: nothing, so a complete record needs ``jax.clear_caches()`` first.
+    kernel_routes: Dict = dataclasses.field(default_factory=dict)
 
     @property
     def total_embeddings(self) -> int:

@@ -20,20 +20,16 @@ it produces, **on device**,
     exact pow2 capacity.
 
 The row sort itself stays on ``jax.lax.sort`` — XLA's tuned variadic sort
-network, which a hand-rolled Pallas sort would not beat. What the Pallas
-kernel (``seg_unique_pallas``) fuses is everything *after* the sort, the
-four passes XLA otherwise materialises separately in HBM: segment-boundary
-detection carry, exclusive prefix-sum of the boundary flags, the
-first-occurrence scatter into the unique window, and the per-slot count
-accumulation — one VMEM pass with the running unique total carried across
-the sequential grid (the same revisited-window dataflow as
-``kernels/compact.py``).
+network. After the sort, segment ids are the inclusive prefix sum of the
+segment-boundary flags; the kernel route (``seg_unique_pallas``) computes
+that scan with the Pallas prefix-sum kernel of ``kernels/compact.py`` and
+leaves the first-occurrence scatter and the per-slot count reduction to
+XLA (Mosaic lowers neither ``cumsum`` nor a vector scatter).
 
 Dispatch follows :mod:`repro.kernels.dispatch`: ``interpret=None``
-compiles on TPU/GPU and interprets on CPU; the engine's
-``aggregate_kernel=None`` auto-knob only routes here where Pallas lowers
-natively (TPU). The jnp route (``seg_unique_ref``) honours the identical
-contract, so the two are interchangeable inside one jitted program.
+compiles on TPU and interprets elsewhere. The jnp route
+(``seg_unique_ref``) honours the identical contract, so the two are
+interchangeable inside one jitted program.
 """
 from __future__ import annotations
 
@@ -41,13 +37,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
-from repro.kernels.dispatch import resolve_interpret
-
-#: bytes of VMEM-resident unique windows (src + counts, int32 each) we
-#: allow; larger capacities route to the jnp segment path.
-VMEM_SLOT_LIMIT = 4 * 2**20
+from repro.kernels import compact
 
 #: the int32 count ceiling (DESIGN.md §13): when a pipeline stage must
 #: narrow per-pattern counts to int32 (the fused chunk programs' partial
@@ -59,110 +50,12 @@ VMEM_SLOT_LIMIT = 4 * 2**20
 I32_SAT = 2**31 - 1
 
 
-def fits_vmem(cap: int) -> bool:
-    """True when the two (cap + 1) int32 slot windows are VMEM-sized."""
-    return (int(cap) + 1) * 4 * 2 <= VMEM_SLOT_LIMIT
-
-
-def _seg_unique_kernel(new_ref, valid_ref, src_ref, counts_ref, slot_ref,
-                       n_ref):
-    """One grid step over a block of sorted rows: boundary prefix-sum +
-    first-occurrence scatter + count accumulate, with ``n_ref`` doubling as
-    the cross-block carry of the running distinct total (the compact.py
-    revisited-window idiom)."""
-    i = pl.program_id(0)
-    block = new_ref.shape[0]
-    slots = src_ref.shape[0]              # cap + 1 (last slot = dump)
-
-    @pl.when(i == 0)
-    def _init():
-        src_ref[...] = jnp.zeros((slots,), jnp.int32)
-        counts_ref[...] = jnp.zeros((slots,), jnp.int32)
-        n_ref[...] = jnp.zeros((1,), jnp.int32)
-
-    new = new_ref[...]
-    valid = valid_ref[...]
-    newv = new & valid
-    base = n_ref[0]
-    # inclusive prefix sum of boundary flags, offset by the carried base:
-    # slot of a row = (#boundaries at or before it) - 1 (dtypes pinned —
-    # the repo enables x64, which would promote the sums)
-    incl = jnp.cumsum(newv.astype(jnp.int32), dtype=jnp.int32)
-    slot = jnp.where(valid, base + incl - 1, -1)
-    # global source index of every row in this block (2-D iota: TPU has no
-    # 1-D iota — see the canonical-check kernels)
-    src = i * block + jax.lax.broadcasted_iota(jnp.int32, (block, 1), 0)[:, 0]
-    # first occurrences scatter their source index; overflowed and
-    # non-boundary rows land in the dump slot (sliced off by the wrapper)
-    pos_src = jnp.where(newv & (slot < slots - 1), slot, slots - 1)
-    src_ref[...] = src_ref[...].at[pos_src].set(jnp.where(newv, src, 0))
-    # per-slot count accumulate (duplicates within the block fold via .add)
-    pos_cnt = jnp.where(valid & (slot >= 0) & (slot < slots - 1),
-                        slot, slots - 1)
-    counts_ref[...] = counts_ref[...].at[pos_cnt].add(valid.astype(jnp.int32))
-    slot_ref[...] = slot
-    n_ref[...] = (base + newv.sum(dtype=jnp.int32)).reshape(1)
-
-
-@functools.partial(
-    jax.jit, static_argnames=("cap", "block", "interpret")
-)
-def seg_unique_pallas(new, valid, cap: int, block: int = 8192,
-                      interpret=None):
-    """(new (B,) bool boundary flags, valid (B,) bool) over SORTED rows ->
-    (src (cap,) int32, counts (cap,) int32, slot (B,) int32, n () int32).
-
-    ``src[:min(n, cap)]`` are the first-occurrence indices of each distinct
-    segment in ascending order (pad slots 0); ``counts`` the per-segment
-    row totals; ``slot`` the per-row segment id (-1 invalid, unclamped past
-    ``cap``); ``n`` the unclamped distinct total. Valid rows must form a
-    prefix of the sort order (the code sort pushes invalid rows last).
-    """
-    b = new.shape[0]
-    if b == 0:
-        return (jnp.zeros((cap,), jnp.int32), jnp.zeros((cap,), jnp.int32),
-                jnp.zeros((0,), jnp.int32), jnp.zeros((), jnp.int32))
-    block = max(1, min(block, b))
-    pad = (-b) % block
-    if pad:
-        new = jnp.concatenate([new, jnp.zeros((pad,), new.dtype)])
-        valid = jnp.concatenate([valid, jnp.zeros((pad,), valid.dtype)])
-
-    src, counts, slot, n = pl.pallas_call(
-        _seg_unique_kernel,
-        grid=((b + pad) // block,),
-        in_specs=[
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((block,), lambda i: (i,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((cap + 1,), lambda i: (0,)),   # revisited window
-            pl.BlockSpec((cap + 1,), lambda i: (0,)),   # revisited window
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((1,), lambda i: (0,)),         # carry + result
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((cap + 1,), jnp.int32),
-            jax.ShapeDtypeStruct((cap + 1,), jnp.int32),
-            jax.ShapeDtypeStruct((b + pad,), jnp.int32),
-            jax.ShapeDtypeStruct((1,), jnp.int32),
-        ],
-        interpret=resolve_interpret(interpret),
-    )(new, valid)
-    return src[:cap], counts[:cap], slot[:b], n[0]
-
-
-def seg_unique_ref(new, valid, cap: int):
-    """The jnp route (cumsum + scatter + segment_sum) with the kernel's
-    exact contract — what ``bin_rows`` uses when the kernel is off."""
-    b = new.shape[0]
-    if b == 0:
-        return (jnp.zeros((cap,), jnp.int32), jnp.zeros((cap,), jnp.int32),
-                jnp.zeros((0,), jnp.int32), jnp.zeros((), jnp.int32))
-    newv = new & valid
-    incl = jnp.cumsum(newv.astype(jnp.int32), dtype=jnp.int32)
+def _seg_tail(newv, valid, cap: int, incl, n):
+    """Slots, first-occurrence sources and per-slot counts from the
+    inclusive prefix sum ``incl`` of the boundary flags ``newv`` — the
+    part of the segment-unique shared by both routes."""
+    b = newv.shape[0]
     slot = jnp.where(valid, incl - 1, -1)
-    n = incl[-1]
     iota = jnp.arange(b, dtype=jnp.int32)
     pos_src = jnp.where(newv & (slot < cap), slot, cap)
     src = jnp.zeros((cap + 1,), jnp.int32).at[pos_src].set(
@@ -173,6 +66,45 @@ def seg_unique_ref(new, valid, cap: int):
         valid.astype(jnp.int32), pos_cnt, num_segments=cap + 1
     )[:cap].astype(jnp.int32)
     return src, counts, slot, n
+
+
+def _seg_empty(cap: int):
+    return (jnp.zeros((cap,), jnp.int32), jnp.zeros((cap,), jnp.int32),
+            jnp.zeros((0,), jnp.int32), jnp.zeros((), jnp.int32))
+
+
+@functools.partial(
+    jax.jit, static_argnames=("cap", "block", "interpret")
+)
+def seg_unique_pallas(new, valid, cap: int, block: int = 32768,
+                      interpret=None):
+    """(new (B,) bool boundary flags, valid (B,) bool) over SORTED rows ->
+    (src (cap,) int32, counts (cap,) int32, slot (B,) int32, n () int32).
+
+    ``src[:min(n, cap)]`` are the first-occurrence indices of each distinct
+    segment in ascending order (pad slots 0); ``counts`` the per-segment
+    row totals; ``slot`` the per-row segment id (-1 invalid, unclamped past
+    ``cap``); ``n`` the unclamped distinct total. Valid rows must form a
+    prefix of the sort order (the code sort pushes invalid rows last).
+    The boundary-flag prefix sum runs in the Pallas scan
+    (``compact.prefix_sum_pallas``); the scatters stay in XLA.
+    """
+    if new.shape[0] == 0:
+        return _seg_empty(cap)
+    newv = new & valid
+    incl, n = compact.prefix_sum_pallas(newv, block=block,
+                                        interpret=interpret)
+    return _seg_tail(newv, valid, cap, incl, n)
+
+
+def seg_unique_ref(new, valid, cap: int):
+    """The jnp route (cumsum + scatter + segment_sum) with the kernel's
+    exact contract — what ``bin_rows`` uses when the kernel is off."""
+    if new.shape[0] == 0:
+        return _seg_empty(cap)
+    newv = new & valid
+    incl = jnp.cumsum(newv.astype(jnp.int32), dtype=jnp.int32)
+    return _seg_tail(newv, valid, cap, incl, incl[-1])
 
 
 def sort_codes(codes, valid):
@@ -199,7 +131,7 @@ def sort_codes(codes, valid):
 
 
 def bin_rows(codes, valid, cap: int, weights=None, *, use_kernel: bool = False,
-             block: int = 8192, interpret=None, method: str = "sort"):
+             block: int = 32768, interpret=None, method: str = "sort"):
     """Level-1 device binning of one batch of quick codes.
 
     ``codes`` (B, 3) int64, ``valid`` (B,) bool ->
@@ -252,7 +184,7 @@ def bin_rows(codes, valid, cap: int, weights=None, *, use_kernel: bool = False,
         [jnp.ones((1,), bool), (sc[1:] != sc[:-1]).any(axis=1)]
     )
     new = sv & prev_diff
-    if use_kernel and fits_vmem(cap):
+    if use_kernel:
         src, counts32, slot, n = seg_unique_pallas(
             new, sv, cap, block=block, interpret=interpret
         )

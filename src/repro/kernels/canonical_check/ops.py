@@ -10,7 +10,7 @@ Dispatch rules (the contract the engine relies on):
   * **Fused-expansion limit** — :func:`expand_canonical` additionally keeps
     the padded neighbour table in VMEM; both structures together must fit
     under :data:`VMEM_FUSED_LIMIT`.
-  * **interpret auto-detection** — ``interpret=None`` compiles on TPU/GPU
+  * **interpret auto-detection** — ``interpret=None`` compiles on TPU
     and interprets on CPU (``repro.kernels.dispatch.resolve_interpret``).
   * **edge mode** — there is no edge-mode kernel yet; ``mode="edge"``
     always routes to the jnp ``canonical.edge_check``. Callers go through
@@ -26,6 +26,7 @@ import jax.numpy as jnp
 
 from repro.core import bitset, canonical
 from repro.core.graph import DeviceGraph
+from repro.kernels.dispatch import note_route
 from repro.kernels.canonical_check.canonical_check import (
     canonical_check_pallas,
     canonical_check_tiles_pallas,
@@ -73,8 +74,10 @@ def canonical_check(g: DeviceGraph, members, n_valid, cand, *,
     """Alg.-2 check: kernel path for VMEM-sized graphs (vertex mode), jnp
     fallback otherwise. Accepts any batch size, including 0."""
     if mode == "edge":
+        note_route("canonical_check", "jnp:edge-mode")
         return canonical.edge_check(g, members, n_valid, cand)
     if not fits_vmem(g):
+        note_route("canonical_check", "jnp:bitmap-exceeds-vmem")
         return canonical.vertex_check(g, members, n_valid, cand)
     return canonical_check_pallas(
         members, n_valid, cand, g.adj_bits, block_b=block_b, interpret=interpret
@@ -109,7 +112,11 @@ def canonical_check_tiles(members, ranks, n_valid, cand, adj_tile, *,
     route otherwise — the halo is frontier-sized, not graph-sized, so the
     kernel stays live on graphs whose full bitmap long overflowed
     :data:`VMEM_BITMAP_LIMIT`."""
-    if not use_pallas or adj_tile.size * 4 > VMEM_BITMAP_LIMIT:
+    if not use_pallas:
+        return canonical_check_tiles_ref(members, ranks, n_valid, cand,
+                                         adj_tile)
+    if adj_tile.size * 4 > VMEM_BITMAP_LIMIT:
+        note_route("canonical_check", "jnp:tile-exceeds-vmem")
         return canonical_check_tiles_ref(members, ranks, n_valid, cand,
                                          adj_tile)
     return canonical_check_tiles_pallas(

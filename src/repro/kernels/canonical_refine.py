@@ -21,14 +21,16 @@ all bit-identical to :func:`canon_math.canonicalize_one` /
 Dataflow: permutations act on the *encoded* words directly — a host-built
 per-nv table (``canon_math.perm_tables``) maps each target adjacency bit
 to its source bit under every permutation, so a permuted w0 is 28 shift/or
-ops per permutation tile and never touches a dense (nv, nv) matrix. All
-kernel arithmetic is uint32 (every code word < 2^32 by construction) and
+ops per permutation tile and never touches a dense (nv, nv) matrix. Code
+words are 32-bit patterns (every code word < 2^32 by construction) and
 the permutation axis is tiled with a running cross-tile argmin whose
 strict-less merge preserves the first-minimal-wins tie-break.
 
 Routes: ``_refine_nv_jnp`` (lax.fori_loop over permutation tiles) and
 ``_refine_nv_pallas`` (grid = rows × permutation tiles, revisited output
-windows carrying the running best — the compact.py idiom). Same contract,
+windows carrying the running best — the compact.py idiom; int32 words
+compared through an order-preserving bias, since Mosaic reduces no
+unsigned integers). Same contract,
 interchangeable inside one jitted program; dispatch follows
 :mod:`repro.kernels.dispatch`.
 """
@@ -42,7 +44,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 
 from repro.core import canon_math
-from repro.kernels.dispatch import resolve_interpret
+from repro.kernels.dispatch import pallas_call
 
 #: permutation-axis tile (the fori/grid step); 128 divides 8! and bounds
 #: the (rows × tile) key intermediates to VMEM-friendly sizes.
@@ -219,48 +221,83 @@ def _refine_nv_jnp(codes, nv: int, with_orbits: bool, tile: int):
 # Pallas route
 # ---------------------------------------------------------------------------
 
+#: signed-order bias: ``x ^ _BIAS`` maps uint32 order onto int32 order, so
+#: the kernel compares and reduces signed words (Mosaic reduces no
+#: unsigned integers).
+_BIAS = -(2**31)
+_I32_MAX = 2**31 - 1
+
+
 def _refine_kernel(codes_ref, labels_ref, perms_ref, src_ref,
-                   best_ref, pi_ref, rep_ref, *, nv: int, tile: int,
+                   best_ref, rep_ref, *, nv: int, tile: int,
                    with_orbits: bool):
     """Grid step (i, j) = (row block, permutation tile): permute keys for
-    the tile, fold its first-min into the revisited best/pi/rep windows.
+    the tile, fold its first-min into the revisited best/rep windows.
     The permutation axis is the FAST grid dimension, so for a fixed row
     block j sweeps all tiles before i advances — the running windows carry
-    across j and re-initialise at j == 0."""
+    across j and re-initialise at j == 0.
+
+    Rows lie on sublanes and permutations on lanes: ``perms_ref`` /
+    ``src_ref`` arrive transposed ((8, T) / (28, T)), so every key plane
+    is an (R, T) int32 tile. Words are uint32 bit patterns held in int32;
+    ``best_ref`` columns 0-2 hold the running minimum in biased (signed)
+    form and column 3 its permutation index."""
     j = pl.program_id(1)
+    rows = best_ref.shape[0]
+    col8 = jax.lax.broadcasted_iota(jnp.int32, (rows, 8), 1)
 
     @pl.when(j == 0)
     def _init():
-        best_ref[...] = jnp.full(best_ref.shape, _U32_MAX, jnp.uint32)
-        pi_ref[...] = jnp.zeros(pi_ref.shape, jnp.int32)
-        rep_ref[...] = jax.lax.broadcasted_iota(
-            jnp.int32, rep_ref.shape, 1
-        )
+        best_ref[...] = jnp.where(col8 < 3, jnp.int32(_I32_MAX), 0)
+        rep_ref[...] = col8
 
-    codes = codes_ref[...]                                    # (R, 3) uint32
-    bits = codes[:, 0] >> 4
-    labels = labels_ref[...]                                  # (R, 8) uint32
-    pt = perms_ref[...]                                       # (T, 8) int32
-    st = src_ref[...]                                         # (T, 28) int32
-    w0, w1, w2 = _permuted_keys(bits, labels, pt, st, nv)
-    m0, m1, m2, loc = _tile_first_min(w0, w1, w2)
-    tpi = j * tile + loc
-    cur = best_ref[...]
-    better = _lex_less3(m0, m1, m2, cur[:, 0], cur[:, 1], cur[:, 2])
-    best_ref[...] = jnp.stack(
-        [jnp.where(better, m0, cur[:, 0]),
-         jnp.where(better, m1, cur[:, 1]),
-         jnp.where(better, m2, cur[:, 2])], axis=1
-    )
-    pi_ref[...] = jnp.where(better, tpi, pi_ref[...][:, 0])[:, None]
+    codes = codes_ref[...]                                    # (R, 3)
+    bits = codes[:, 0:1] >> 4                                 # < 2^28
+    labels = labels_ref[...]                                  # (R, 8)
+    pt = perms_ref[...]                                       # (8, T)
+    st = src_ref[...]                                         # (28, T)
+    new_bits = jnp.zeros((rows, tile), jnp.int32)
+    for tb in range(canon_math.n_pair_bits(nv)):
+        new_bits = new_bits | (((bits >> st[tb:tb + 1, :]) & 1) << tb)
+    w0 = (new_bits << 4) | nv
+    w1 = jnp.zeros_like(new_bits)
+    w2 = jnp.zeros_like(new_bits)
+    for i in range(8):
+        pti = pt[i:i + 1, :]                                  # (1, T)
+        li = jnp.zeros_like(new_bits)
+        for src in range(8):
+            li = jnp.where(pti == src, labels[:, src:src + 1], li)
+        if i < 4:
+            w1 = w1 | (li << (8 * i))
+        else:
+            w2 = w2 | (li << (8 * (i - 4)))
+    b0, b1, b2 = w0 ^ _BIAS, w1 ^ _BIAS, w2 ^ _BIAS
+    # first lexicographic minimum over the tile (three-stage masked min,
+    # then the lowest column achieving it)
+    m0 = b0.min(axis=1, keepdims=True)
+    e = b0 == m0
+    m1 = jnp.where(e, b1, _I32_MAX).min(axis=1, keepdims=True)
+    e = e & (b1 == m1)
+    m2 = jnp.where(e, b2, _I32_MAX).min(axis=1, keepdims=True)
+    e = e & (b2 == m2)
+    col = jax.lax.broadcasted_iota(jnp.int32, (rows, tile), 1)
+    loc = jnp.where(e, col, tile).min(axis=1, keepdims=True)
+    best = best_ref[...]
+    c0, c1, c2 = best[:, 0:1], best[:, 1:2], best[:, 2:3]
+    better = _lex_less3(m0, m1, m2, c0, c1, c2)
+    upd = jnp.where(col8 == 0, m0, jnp.where(col8 == 1, m1, jnp.where(
+        col8 == 2, m2, j * tile + loc)))
+    best_ref[...] = jnp.where(better & (col8 < 4), upd, best)
     if with_orbits:
-        auto = (
-            (w0 == codes[:, 0:1]) & (w1 == codes[:, 1:2])
-            & (w2 == codes[:, 2:3])
-        )
-        cand = jnp.where(auto[:, :, None], pt[None, :, :],
-                         jnp.int32(8)).min(axis=1)
-        rep_ref[...] = jnp.minimum(rep_ref[...], cand)
+        auto = ((w0 == codes[:, 0:1]) & (w1 == codes[:, 1:2])
+                & (w2 == codes[:, 2:3]))
+        rep = rep_ref[...]
+        for pos in range(8):
+            cand = jnp.where(auto, pt[pos:pos + 1, :], 8).min(
+                axis=1, keepdims=True
+            )
+            rep = jnp.where(col8 == pos, jnp.minimum(rep, cand), rep)
+        rep_ref[...] = rep
 
 
 def _refine_nv_pallas(codes, nv: int, with_orbits: bool, tile: int,
@@ -270,38 +307,40 @@ def _refine_nv_pallas(codes, nv: int, with_orbits: bool, tile: int,
     q = codes.shape[0]
     perms_np, src_np = _padded_tables(nv, tile)
     perms_dev = jnp.asarray(perms_np)
-    src_dev = jnp.asarray(src_np)
     _, labels, cu = _split_codes(codes)
-    row_block = max(1, min(row_block, q))
+    words = jax.lax.bitcast_convert_type(cu, jnp.int32)
+    labels = labels.astype(jnp.int32)
+    row_block = -(-max(1, min(row_block, q)) // 8) * 8
     pad = (-q) % row_block
     if pad:
-        cu = jnp.concatenate([cu, jnp.zeros((pad, 3), jnp.uint32)])
-        labels = jnp.concatenate([labels, jnp.zeros((pad, 8), jnp.uint32)])
+        words = jnp.concatenate([words, jnp.zeros((pad, 3), jnp.int32)])
+        labels = jnp.concatenate([labels, jnp.zeros((pad, 8), jnp.int32)])
     n_tiles = len(perms_np) // tile
-    best, bpi, rep = pl.pallas_call(
+    best, rep = pallas_call(
         functools.partial(_refine_kernel, nv=nv, tile=tile,
                           with_orbits=with_orbits),
+        name="canonical_refine",
         grid=((q + pad) // row_block, n_tiles),
         in_specs=[
             pl.BlockSpec((row_block, 3), lambda i, j: (i, 0)),
             pl.BlockSpec((row_block, 8), lambda i, j: (i, 0)),
-            pl.BlockSpec((tile, 8), lambda i, j: (j, 0)),
-            pl.BlockSpec((tile, MAX_BITS), lambda i, j: (j, 0)),
+            pl.BlockSpec((8, tile), lambda i, j: (0, j)),
+            pl.BlockSpec((MAX_BITS, tile), lambda i, j: (0, j)),
         ],
         out_specs=[
-            pl.BlockSpec((row_block, 3), lambda i, j: (i, 0)),
-            pl.BlockSpec((row_block, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((row_block, 8), lambda i, j: (i, 0)),
             pl.BlockSpec((row_block, 8), lambda i, j: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((q + pad, 3), jnp.uint32),
-            jax.ShapeDtypeStruct((q + pad, 1), jnp.int32),
+            jax.ShapeDtypeStruct((q + pad, 8), jnp.int32),
             jax.ShapeDtypeStruct((q + pad, 8), jnp.int32),
         ],
-        interpret=resolve_interpret(interpret),
-    )(cu, labels, perms_dev, src_dev)
-    canon = best[:q].astype(jnp.int64)
-    sigma = _sigma_from_pi(bpi[:q, 0], perms_dev)
+        interpret=interpret,
+    )(words, labels, jnp.asarray(perms_np.T), jnp.asarray(src_np.T))
+    canon = jax.lax.bitcast_convert_type(
+        best[:q, :3] ^ jnp.int32(_BIAS), jnp.uint32
+    ).astype(jnp.int64)
+    sigma = _sigma_from_pi(best[:q, 3], perms_dev)
     return canon, sigma, rep[:q]
 
 

@@ -1,13 +1,22 @@
-"""Stream compaction (block prefix-sum + scatter) as a Pallas kernel.
+"""Stream compaction (block prefix sum + scatter) with a Pallas scan.
 
 The chunk program of the fused superstep pipeline (DESIGN.md §8) must turn
 a flat keep mask over candidate slots into the dense child frontier. The
-jnp route is ``jnp.nonzero(keep, size=out_cap, fill_value=0)`` — a full
-sort-based gather that XLA materialises in HBM per chunk. This kernel
-replaces it with the classic stream-compaction dataflow: the grid walks
-``keep`` in blocks, each block computes its exclusive prefix sum, adds the
-running total carried across the (sequential) grid, and scatters its kept
-global indices straight into the VMEM-resident output window.
+jnp route is ``jnp.nonzero(keep, size=out_cap, fill_value=0)``. The kernel
+route splits the classic stream-compaction dataflow in two:
+
+  * :func:`prefix_sum_pallas` — the scan, as a Pallas kernel. The flags
+    are laid out lane-dense as ``(rows, 128)``; each grid step takes a
+    ``(block_rows, 128)`` tile, computes its inclusive prefix sum with two
+    MXU matmuls (row-wise against an upper-triangular ones block, then the
+    row totals against a strictly-lower-triangular block — exact, since
+    every partial sum is a small integer), and adds the running total
+    carried across the sequential grid in a revisited output tile. Mosaic
+    has no ``cumsum`` lowering; the matmul scan is what it lowers.
+  * the scatter of kept source indices to their output positions, done by
+    XLA (Mosaic has no vector scatter either).
+
+The same scan serves the level-1 segment-unique of ``kernels/aggregate.py``.
 
 Contract (identical to the jnp route, so the two are interchangeable
 inside one jitted chunk program):
@@ -20,19 +29,7 @@ inside one jitted chunk program):
     path sync-free (``repro.core.runtime.serial``).
 
 Dispatch follows the shared rules in :mod:`repro.kernels.dispatch`:
-``interpret=None`` compiles on TPU/GPU and interprets on CPU; the engine's
-``compact_kernel=None`` auto-knob only routes here where Pallas lowers
-natively (TPU), everything else keeps the jnp route. Like the
-canonical-check kernels, the compiled (Mosaic) path is the TPU target; the
-Triton lowering of the in-kernel scatter has not been validated, so GPU
-remains opt-in.
-
-The output window is revisited (read-modified-written) by every grid
-step, so total traffic is O(n_blocks * out_cap) — the same
-VMEM-resident-window tradeoff as the canonical-check bitmap. Callers
-guard with :func:`fits_vmem` (``explore.compact`` falls back to the jnp
-gather past :data:`VMEM_IDX_LIMIT`) and the default block is sized large
-to keep the number of window passes small.
+``interpret=None`` compiles on TPU and interprets elsewhere.
 """
 from __future__ import annotations
 
@@ -42,83 +39,102 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.dispatch import resolve_interpret
+from repro.kernels.dispatch import pallas_call
 
-#: bytes of packed-index output window we allow resident in VMEM; larger
-#: capacities route to the jnp nonzero gather (streamed from HBM by XLA).
-VMEM_IDX_LIMIT = 4 * 2**20
-
-
-def fits_vmem(out_cap: int) -> bool:
-    """True when the (out_cap + 1) int32 index window is VMEM-sized."""
-    return (int(out_cap) + 1) * 4 <= VMEM_IDX_LIMIT
+LANES = 128
+#: sublane multiple of a 32-bit tile: blocks are whole (8, 128) tiles.
+SUBLANES = 8
 
 
-def _compact_kernel(keep_ref, idx_ref, count_ref):
-    """One grid step: block prefix-sum + scatter with a running carry.
+def _prefix_kernel(flags_ref, incl_ref, carry_ref):
+    """One grid step: inclusive prefix sum of a (rows, 128) 0/1 tile in
+    row-major order, offset by the total carried from earlier steps.
 
-    ``idx_ref``/``count_ref`` use constant index maps, so the same output
-    window is revisited by every (sequential) grid step — ``count_ref``
-    doubles as the cross-block carry of the running kept total.
-    """
-    i = pl.program_id(0)
-    block = keep_ref.shape[0]
-    out_slots = idx_ref.shape[0]          # out_cap + 1 (last slot = dump)
-
-    @pl.when(i == 0)
+    ``carry_ref`` uses a constant index map, so the same (8, 128) tile is
+    revisited by every (sequential) grid step; every element holds the
+    running total."""
+    @pl.when(pl.program_id(0) == 0)
     def _init():
-        idx_ref[...] = jnp.zeros((out_slots,), jnp.int32)
-        count_ref[...] = jnp.zeros((1,), jnp.int32)
+        carry_ref[...] = jnp.zeros(carry_ref.shape, jnp.int32)
 
-    keep = keep_ref[...]
-    kept = keep.astype(jnp.int32)
-    base = count_ref[0]
-    # exclusive prefix sum inside the block, offset by the carried base
-    # (dtypes pinned: the repo enables x64, which would promote the sums)
-    local = jnp.cumsum(kept, dtype=jnp.int32) - kept
-    gpos = base + local
-    # global source index of every slot in this block (2-D iota: TPU has no
-    # 1-D iota — see the canonical-check kernels)
-    src = i * block + jax.lax.broadcasted_iota(jnp.int32, (block, 1), 0)[:, 0]
-    # scatter kept sources to their output position; dropped and overflowed
-    # slots land in the dump slot (sliced off by the wrapper)
-    pos = jnp.where(keep & (gpos < out_slots - 1), gpos, out_slots - 1)
-    idx_ref[...] = idx_ref[...].at[pos].set(jnp.where(keep, src, 0))
-    count_ref[...] = (base + kept.sum(dtype=jnp.int32)).reshape(1)
+    x = flags_ref[...].astype(jnp.bfloat16)                 # (R, 128) 0/1
+    rows = x.shape[0]
+    r = jax.lax.broadcasted_iota(jnp.int32, (LANES, LANES), 0)
+    c = jax.lax.broadcasted_iota(jnp.int32, (LANES, LANES), 1)
+    upper = (r <= c).astype(jnp.bfloat16)
+    # within-row inclusive prefix (each entry <= 128, exact)
+    within = jnp.dot(x, upper, preferred_element_type=jnp.float32)
+    totals = jnp.broadcast_to(within[:, LANES - 1:LANES], (rows, LANES))
+    rr = jax.lax.broadcasted_iota(jnp.int32, (rows, rows), 0)
+    rc = jax.lax.broadcasted_iota(jnp.int32, (rows, rows), 1)
+    lower = (rc < rr).astype(jnp.bfloat16)
+    # exclusive prefix of the row totals (each total <= 128 is exact in
+    # bf16; the f32 sums stay below 2^24)
+    before = jnp.dot(lower, totals.astype(jnp.bfloat16),
+                     preferred_element_type=jnp.float32)
+    base = carry_ref[0:1, :]
+    incl_ref[...] = base + (within + before).astype(jnp.int32)
+    block_total = (before[rows - 1:rows, :]
+                   + totals[rows - 1:rows, :]).astype(jnp.int32)
+    carry_ref[...] = jnp.broadcast_to(base + block_total, carry_ref.shape)
+
+
+def _block_rows(block: int) -> int:
+    """Rows of 128 lanes per grid step for a requested ``block`` of
+    elements: whole (8, 128) tiles, at least one."""
+    rows = -(-max(int(block), 1) // LANES)
+    return -(-rows // SUBLANES) * SUBLANES
+
+
+@functools.partial(jax.jit, static_argnames=("block", "interpret"))
+def prefix_sum_pallas(flags, block: int = 32768, interpret=None):
+    """flags (B,) bool -> (incl (B,) int32, total () int32): the inclusive
+    prefix sum of ``flags`` and its total. Any ``B`` including 0."""
+    n = flags.shape[0]
+    if n == 0:
+        return jnp.zeros((0,), jnp.int32), jnp.zeros((), jnp.int32)
+    rows = _block_rows(min(block, n))
+    per_block = rows * LANES
+    pad = (-n) % per_block
+    x = jnp.concatenate(
+        [flags.astype(jnp.int32), jnp.zeros((pad,), jnp.int32)]
+    ).reshape(-1, LANES)
+    incl, carry = pallas_call(
+        _prefix_kernel,
+        name="prefix_sum",
+        grid=(x.shape[0] // rows,),
+        in_specs=[pl.BlockSpec((rows, LANES), lambda i: (i, 0))],
+        out_specs=[
+            pl.BlockSpec((rows, LANES), lambda i: (i, 0)),
+            pl.BlockSpec((SUBLANES, LANES), lambda i: (0, 0)),  # carry
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct(x.shape, jnp.int32),
+            jax.ShapeDtypeStruct((SUBLANES, LANES), jnp.int32),
+        ],
+        interpret=interpret,
+    )(x)
+    return incl.reshape(-1)[:n], carry[0, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("out_cap", "block", "interpret"))
-def stream_compact_pallas(keep, out_cap: int, block: int = 8192,
+def stream_compact_pallas(keep, out_cap: int, block: int = 32768,
                           interpret=None):
     """keep (B,) bool -> (idx (out_cap,) int32, count () int32).
 
     ``idx[:min(count, out_cap)]`` are the kept positions of ``keep`` in
     ascending order (pad slots 0); ``count`` is the unclamped kept total.
-    Accepts any ``B`` including 0 and non-multiples of ``block``.
+    Accepts any ``B`` including 0.
     """
     n = keep.shape[0]
     if n == 0:
         return jnp.zeros((out_cap,), jnp.int32), jnp.zeros((), jnp.int32)
-    block = max(1, min(block, n))
-    pad = (-n) % block
-    if pad:
-        keep = jnp.concatenate([keep, jnp.zeros((pad,), keep.dtype)])
-
-    idx, count = pl.pallas_call(
-        _compact_kernel,
-        grid=((n + pad) // block,),
-        in_specs=[pl.BlockSpec((block,), lambda i: (i,))],
-        out_specs=[
-            pl.BlockSpec((out_cap + 1,), lambda i: (0,)),   # revisited window
-            pl.BlockSpec((1,), lambda i: (0,)),             # carry + result
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((out_cap + 1,), jnp.int32),
-            jax.ShapeDtypeStruct((1,), jnp.int32),
-        ],
-        interpret=resolve_interpret(interpret),
-    )(keep)
-    return idx[:out_cap], count[0]
+    incl, count = prefix_sum_pallas(keep, block=block, interpret=interpret)
+    pos = jnp.where(keep, incl - 1, out_cap)
+    idx = jnp.zeros((out_cap,), jnp.int32).at[pos].set(
+        jnp.arange(n, dtype=jnp.int32), mode="drop"
+    )
+    return idx, count
 
 
 def stream_compact_ref(keep, out_cap: int):

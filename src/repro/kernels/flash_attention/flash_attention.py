@@ -36,8 +36,8 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, *, block_k: int, sm_scale: float,
 
     def body(i, carry):
         acc, m_prev, l_prev = carry
-        k = pl.load(k_ref, (pl.dslice(i * block_k, block_k), slice(None)))
-        v = pl.load(v_ref, (pl.dslice(i * block_k, block_k), slice(None)))
+        k = k_ref[pl.ds(i * block_k, block_k), :]
+        v = v_ref[pl.ds(i * block_k, block_k), :]
         scores = q @ k.astype(jnp.float32).T                   # (BQ, BK)
         if causal:
             k_pos = i * block_k + jax.lax.iota(jnp.int32, block_k)

@@ -19,10 +19,11 @@ Two pieces, same dispatch idioms as ``compact.py``:
     Pad slots hold the sentinel ``n`` (one past the last vertex id), which
     keeps the tile *ascending* — rank translation in the tile view is a
     single ``searchsorted``.
-  * :func:`gather_rows` — the new Pallas kernel: the shard-stacked table
+  * :func:`gather_rows` — the Pallas kernel: the shard-stacked table
     is kept **VMEM-resident** (same residency pattern as the
-    canonical-check bitmap) and each grid step gathers one block of halo
-    rows out of it. ``gather_rows_ref`` is the jnp route with the exact
+    canonical-check bitmap) and each grid step copies one block of halo
+    rows out of it, one dynamic-offset row load per row id read from
+    SMEM (Mosaic has no in-kernel vector gather). ``gather_rows_ref`` is the jnp route with the exact
     same contract; :func:`fits_vmem` guards the residency, larger tables
     fall back to the jnp gather streamed from HBM.
 
@@ -36,14 +37,17 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import compact as compact_lib
-from repro.kernels.dispatch import resolve_interpret
+from repro.kernels.dispatch import RESIDENT_VMEM_BYTES, note_route, pallas_call
 
 #: bytes of gathered-from table we allow resident in VMEM; larger tables
 #: route to the jnp gather (streamed from HBM by XLA) — same budget shape
 #: as the canonical-check bitmap limit.
 VMEM_TABLE_LIMIT = 8 * 2**20
+#: rows gathered per grid step: one 1-D SMEM tile of row ids.
+ROW_BLOCK = 1024
 
 
 def fits_vmem(table) -> bool:
@@ -52,39 +56,50 @@ def fits_vmem(table) -> bool:
 
 
 def _gather_kernel(rows_ref, table_ref, out_ref):
-    """One grid step: gather a block of table rows. The source table uses a
-    constant index map, so it stays VMEM-resident across the grid."""
-    rows = rows_ref[...]                    # (block,) int32
-    table = table_ref[...]                  # (N, R) — resident
-    out_ref[...] = table[jnp.clip(rows, 0, table.shape[0] - 1)]
+    """One grid step: copy a block of table rows. ``rows_ref`` is the
+    block's row ids in SMEM; the source table uses a constant index map,
+    so it stays VMEM-resident across the grid and each row is one
+    dynamic-offset row load."""
+    def body(j, carry):
+        out_ref[pl.ds(j, 1), :] = table_ref[pl.ds(rows_ref[j], 1), :]
+        return carry
+
+    jax.lax.fori_loop(0, out_ref.shape[0], body, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
-def gather_rows_pallas(table, rows, block: int = 1024, interpret=None):
-    """table (N, R); rows (U,) int32 -> (U, R) = table[rows], no masking
-    (callers apply the fill; see :func:`gather_rows`). Any ``U`` accepted —
-    padded internally to a block multiple and sliced back."""
+def gather_rows_pallas(table, rows, block: int = ROW_BLOCK, interpret=None):
+    """table (N, R) of a 32-bit dtype; rows (U,) int32 -> (U, R) =
+    table[clip(rows)], no masking (callers apply the fill; see
+    :func:`gather_rows`). Any ``U`` accepted — padded internally to a
+    multiple of ``block`` (rounded up to :data:`ROW_BLOCK`, the 1-D SMEM
+    tile) and sliced back."""
     u = rows.shape[0]
     n, r = table.shape
     if u == 0:
         return jnp.zeros((0, r), table.dtype)
-    block = max(1, min(block, u))
+    block = -(-max(int(block), 1) // ROW_BLOCK) * ROW_BLOCK
     pad = (-u) % block
+    rows = jnp.clip(rows.astype(jnp.int32), 0, n - 1)
     if pad:
-        rows = jnp.concatenate([rows, jnp.zeros((pad,), rows.dtype)])
+        rows = jnp.concatenate([rows, jnp.zeros((pad,), jnp.int32)])
+    src = jax.lax.bitcast_convert_type(table, jnp.int32)
 
-    out = pl.pallas_call(
+    out = pallas_call(
         _gather_kernel,
+        name="gather_rows",
         grid=((u + pad) // block,),
         in_specs=[
-            pl.BlockSpec((block,), lambda i: (i,)),
+            pl.BlockSpec((block,), lambda i: (i,),
+                         memory_space=pltpu.SMEM),
             pl.BlockSpec((n, r), lambda i: (0, 0)),   # table VMEM-resident
         ],
         out_specs=pl.BlockSpec((block, r), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((u + pad, r), table.dtype),
-        interpret=resolve_interpret(interpret),
-    )(rows, table)
-    return out[:u]
+        out_shape=jax.ShapeDtypeStruct((u + pad, r), jnp.int32),
+        vmem_limit_bytes=RESIDENT_VMEM_BYTES,
+        interpret=interpret,
+    )(rows, src)
+    return jax.lax.bitcast_convert_type(out[:u], table.dtype)
 
 
 def gather_rows_ref(table, rows):
@@ -102,6 +117,8 @@ def gather_rows(table, rows, fill, *, use_kernel: bool = False,
     if use_kernel and fits_vmem(table):
         out = gather_rows_pallas(table, rows, interpret=interpret)
     else:
+        if use_kernel:
+            note_route("gather_rows", "jnp:table-exceeds-vmem")
         out = gather_rows_ref(table, rows)
     ok = (rows >= 0) & (rows < table.shape[0])
     return jnp.where(ok[:, None], out, jnp.asarray(fill, table.dtype))
@@ -122,7 +139,7 @@ def halo_unique(verts, n: int, cap: int, *, use_kernel: bool = False,
     ok = (verts >= 0) & (verts < n)
     slot = jnp.where(ok, verts, n)
     presence = jnp.zeros((n + 1,), bool).at[slot].set(True)[:n]
-    if use_kernel and compact_lib.fits_vmem(cap):
+    if use_kernel:
         idx, count = compact_lib.stream_compact_pallas(
             presence, cap, interpret=interpret
         )

@@ -12,10 +12,11 @@ payload-carrying sort from the bin in both directions:
 * ``radix_sort_codes`` — a multi-pass LSB radix sort in Pallas: one
   8-bit digit per pass over the quick-code words (w2, w1, w0, then the
   invalid flag, least-significant first), each pass a block-histogram
-  kernel + host-free exclusive scan + a stable scatter kernel whose
+  kernel + host-free exclusive scan + a stable rank kernel whose
   per-digit write cursor is carried across the sequential grid in a
-  revisited output window — the same grid-carried-total dataflow as
-  ``kernels/compact.py``. Passes whose digit is constant over the batch
+  revisited output tile — the same grid-carried-total dataflow as
+  ``kernels/compact.py`` — and an XLA scatter that applies the
+  permutation. Passes whose digit is constant over the batch
   (unlabeled graphs zero both label words) are skipped with `lax.cond`,
   so the common workloads pay for the bits they actually use.
 
@@ -44,7 +45,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.dispatch import resolve_interpret
+from repro.kernels.dispatch import pallas_call
 
 #: digit width of one radix pass; 256-entry histograms stay VMEM-trivial.
 RADIX_BITS = 8
@@ -55,9 +56,11 @@ NDIGITS = 1 << RADIX_BITS
 #: interleave with genuine high-digit codes.
 PAD_DIGIT = NDIGITS
 
-#: VMEM budget for the scatter kernel's revisited full-length output
-#: window plus the (block, NDIGITS+1) one-hot rank matrix.
-VMEM_SORT_LIMIT = 8 * 2**20
+#: digit channels padded to whole 128-lane vregs (257 -> 384).
+CHANNELS = 3 * 128
+LANES = 128
+#: sort rows per grid step: one (8, 128) lane-dense tile of digits.
+SORT_BLOCK = 8 * LANES
 
 #: (word index, shift) per pass, least-significant digit first; word
 #: index 3 is the synthesized invalid flag that pushes invalid rows last.
@@ -74,62 +77,81 @@ _PASSES = (
 _SENTINEL = 0xFFFFFFFFFFFFFFFF
 
 
-def radix_fits_vmem(b: int, block: int) -> bool:
-    """True when the scatter kernel's windows fit the VMEM budget: the
-    revisited (b,) int32 payload output plus the block's one-hot ranks."""
-    return b * 4 + block * (NDIGITS + 1) * 4 <= VMEM_SORT_LIMIT
+def _digit_hits(digits_ref):
+    """The block's digits as one-hot rows, 128 sort rows at a time.
+
+    The (S, 128) lane-dense tile is transposed once, so column ``r`` holds
+    sort rows ``r*128 .. r*128+127`` in order; yields ``(r, hit)`` with
+    ``hit`` the (128, CHANNELS) one-hot matrix of those rows."""
+    d_t = digits_ref[...].T                                   # (128, S)
+    chan = jax.lax.broadcasted_iota(jnp.int32, (LANES, CHANNELS), 1)
+    for r in range(d_t.shape[1]):
+        yield r, d_t[:, r:r + 1] == chan
 
 
 def _hist_kernel(digits_ref, hist_ref):
-    """Per-block digit histogram: one (NDIGITS + 1,) row per grid step."""
-    block = digits_ref.shape[0]
-    d = digits_ref[...]
-    chan = jax.lax.broadcasted_iota(jnp.int32, (block, NDIGITS + 1), 1)
-    eq = (d[:, None] == chan).astype(jnp.int32)
-    hist_ref[...] = eq.sum(axis=0, dtype=jnp.int32).reshape(1, NDIGITS + 1)
+    """Digit histogram: every grid step adds its block's per-digit counts
+    to the revisited (8, CHANNELS) accumulator (all 8 rows equal)."""
+    @pl.when(pl.program_id(0) == 0)
+    def _init():
+        hist_ref[...] = jnp.zeros(hist_ref.shape, jnp.int32)
+
+    acc = jnp.zeros((1, CHANNELS), jnp.int32)
+    for _, hit in _digit_hits(digits_ref):
+        acc = acc + hit.astype(jnp.int32).sum(axis=0, keepdims=True)
+    hist_ref[...] += jnp.broadcast_to(acc, hist_ref.shape)
 
 
-def _scatter_kernel(digits_ref, payload_ref, bases_ref, out_ref, cursor_ref):
-    """One stable counting-scatter block: rank every row within its digit
-    bucket (exclusive one-hot prefix sum), place it at the carried
-    per-digit cursor, then advance the cursor by the block histogram —
-    ``cursor_ref`` is the revisited grid-carried total, seeded from the
-    global exclusive scan on the first step (compact.py idiom)."""
-    i = pl.program_id(0)
-    block = digits_ref.shape[0]
-
-    @pl.when(i == 0)
+def _rank_kernel(digits_ref, bases_ref, pos_ref, cursor_ref):
+    """One stable counting-sort block: the destination of every row is
+    the carried cursor of its digit plus its rank among same-digit rows
+    before it. The rank is an inclusive prefix over rows of the one-hot
+    digit matrix, one MXU matmul per 128 rows against a lower-triangular
+    ones block (exact: counts <= 128); ``cursor_ref`` is the revisited
+    grid-carried total, seeded from the global exclusive scan on the
+    first step (compact.py idiom)."""
+    @pl.when(pl.program_id(0) == 0)
     def _init():
         cursor_ref[...] = bases_ref[...]
 
-    d = digits_ref[...]
-    chan = jax.lax.broadcasted_iota(jnp.int32, (block, NDIGITS + 1), 1)
-    onehot = (d[:, None] == chan).astype(jnp.int32)
-    incl = jnp.cumsum(onehot, axis=0, dtype=jnp.int32)
-    # exclusive rank of each row among same-digit rows of this block
-    rank = jnp.take_along_axis(incl, d[:, None], axis=1)[:, 0] - 1
-    cursor = cursor_ref[...]
-    pos = cursor[d] + rank
-    out_ref[...] = out_ref[...].at[pos].set(payload_ref[...])
-    cursor_ref[...] = cursor + incl[-1, :]
+    rr = jax.lax.broadcasted_iota(jnp.int32, (LANES, LANES), 0)
+    rc = jax.lax.broadcasted_iota(jnp.int32, (LANES, LANES), 1)
+    lower = (rc <= rr).astype(jnp.bfloat16)
+    cursor = cursor_ref[0:1, :]                               # (1, CH)
+    s = pos_ref.shape[0]
+    col = jax.lax.broadcasted_iota(jnp.int32, (LANES, s), 1)
+    pos_t = jnp.zeros((LANES, s), jnp.int32)
+    for r, hit in _digit_hits(digits_ref):
+        incl = jnp.dot(lower, hit.astype(jnp.bfloat16),
+                       preferred_element_type=jnp.float32)    # (128, CH)
+        rank = jnp.sum(jnp.where(hit, incl, 0.0), axis=1, keepdims=True)
+        base = jnp.sum(jnp.where(hit, cursor, 0), axis=1, keepdims=True)
+        pos_t = jnp.where(col == r, base + rank.astype(jnp.int32) - 1,
+                          pos_t)
+        cursor = cursor + incl[LANES - 1:LANES, :].astype(jnp.int32)
+    pos_ref[...] = pos_t.T
+    cursor_ref[...] = jnp.broadcast_to(cursor, cursor_ref.shape)
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
-def radix_sort_codes(codes, valid, block: int = 2048, interpret=None):
+def radix_sort_codes(codes, valid, block: int = SORT_BLOCK, interpret=None):
     """Stable LSB-radix sort of (B, 3) quick-code rows, invalid rows last.
 
     Same contract as ``aggregate.sort_codes``: returns (sorted codes,
     sorted valid, order). Each 8-bit pass runs only if its digit varies
     over the batch (`lax.cond`), so e.g. unlabeled motifs pay for the
     structure word alone; the payload permuted through the passes is the
-    row index only — code words are re-gathered per pass on the host-free
-    side of the program.
+    row index only. Per pass, two Pallas kernels compute the digit
+    histogram and every row's destination over lane-dense (8k, 128)
+    digit tiles; XLA applies the permutation (Mosaic has no vector
+    scatter). ``block`` (sort rows per grid step) is rounded up to a
+    multiple of :data:`SORT_BLOCK`.
     """
     b = codes.shape[0]
-    block = max(1, min(block, b))
+    block = -(-max(1, int(block)) // SORT_BLOCK) * SORT_BLOCK
     pad = (-b) % block
     nblocks = (b + pad) // block
-    itp = resolve_interpret(interpret)
+    rows = block // LANES
 
     # word bit-patterns as int32 (quick-code words are < 2^32 by
     # construction); byte-wise digits of the two's-complement pattern
@@ -141,38 +163,37 @@ def radix_sort_codes(codes, valid, block: int = 2048, interpret=None):
     order = jnp.arange(b, dtype=jnp.int32)
     pad_digits = jnp.full((pad,), PAD_DIGIT, jnp.int32)
     pad_payload = jnp.zeros((pad,), jnp.int32)
+    digit_spec = pl.BlockSpec((rows, LANES), lambda i: (i, 0))
+    tile_spec = pl.BlockSpec((8, CHANNELS), lambda i: (0, 0))
+    tile_shape = jax.ShapeDtypeStruct((8, CHANNELS), jnp.int32)
 
     def one_pass(order, digits):
-        dp = jnp.concatenate([digits, pad_digits])
+        dp = jnp.concatenate([digits, pad_digits]).reshape(-1, LANES)
         op = jnp.concatenate([order, pad_payload])
-        hist = pl.pallas_call(
+        hist = pallas_call(
             _hist_kernel,
+            name="radix_hist",
             grid=(nblocks,),
-            in_specs=[pl.BlockSpec((block,), lambda i: (i,))],
-            out_specs=pl.BlockSpec((1, NDIGITS + 1), lambda i: (i, 0)),
-            out_shape=jax.ShapeDtypeStruct((nblocks, NDIGITS + 1), jnp.int32),
-            interpret=itp,
+            in_specs=[digit_spec],
+            out_specs=tile_spec,
+            out_shape=tile_shape,
+            interpret=interpret,
         )(dp)
-        totals = hist.sum(axis=0, dtype=jnp.int32)
+        totals = hist[0]
         bases = jnp.cumsum(totals, dtype=jnp.int32) - totals
-        out, _ = pl.pallas_call(
-            _scatter_kernel,
+        pos, _ = pallas_call(
+            _rank_kernel,
+            name="radix_rank",
             grid=(nblocks,),
-            in_specs=[
-                pl.BlockSpec((block,), lambda i: (i,)),
-                pl.BlockSpec((block,), lambda i: (i,)),
-                pl.BlockSpec((NDIGITS + 1,), lambda i: (0,)),
-            ],
-            out_specs=[
-                pl.BlockSpec((b + pad,), lambda i: (0,)),   # revisited
-                pl.BlockSpec((NDIGITS + 1,), lambda i: (0,)),  # carry
-            ],
+            in_specs=[digit_spec, tile_spec],
+            out_specs=[digit_spec, tile_spec],
             out_shape=[
-                jax.ShapeDtypeStruct((b + pad,), jnp.int32),
-                jax.ShapeDtypeStruct((NDIGITS + 1,), jnp.int32),
+                jax.ShapeDtypeStruct(dp.shape, jnp.int32),
+                tile_shape,
             ],
-            interpret=itp,
-        )(dp, op, bases)
+            interpret=interpret,
+        )(dp, jnp.broadcast_to(bases, (8, CHANNELS)))
+        out = jnp.zeros((b + pad,), jnp.int32).at[pos.reshape(-1)].set(op)
         return out[:b]
 
     for word, shift in _PASSES:
@@ -266,7 +287,7 @@ def _bin_fused(codes, valid, cap, weights, key, widths):
 
 
 def bin_rows_radix(codes, valid, cap: int, weights=None, *,
-                   use_kernel: bool = False, block: int = 8192,
+                   use_kernel: bool = False, block: int = 32768,
                    interpret=None):
     """Level-1 binning with the radix/bucket partition in place of the
     payload-carrying ``lax.sort`` — the exact `aggregate.bin_rows`
@@ -274,7 +295,7 @@ def bin_rows_radix(codes, valid, cap: int, weights=None, *,
     overflow semantics).
 
     ``use_kernel=True`` routes the sort through the Pallas LSB-radix
-    passes (where the batch fits the VMEM budget); otherwise the fused
+    passes; otherwise the fused
     single-key jnp route runs, with a traced `lax.cond` fallback to the
     2-key sort bin for batches whose words exceed 63 used bits.
     """
@@ -289,25 +310,14 @@ def bin_rows_radix(codes, valid, cap: int, weights=None, *,
         weights = jnp.ones((b,), jnp.int64)
 
     if use_kernel:
-        sort_block = max(1, min(2048, b))
-        if not radix_fits_vmem(b + (-b) % sort_block, sort_block):
-            return _agg.bin_rows(
-                codes, valid, cap, weights,
-                use_kernel=use_kernel, block=block, interpret=interpret,
-            )
-        sc, sv, order = radix_sort_codes(
-            codes, valid, block=sort_block, interpret=interpret
-        )
+        sc, sv, order = radix_sort_codes(codes, valid, interpret=interpret)
         prev_diff = jnp.concatenate(
             [jnp.ones((1,), bool), (sc[1:] != sc[:-1]).any(axis=1)]
         )
         new = sv & prev_diff
-        if _agg.fits_vmem(cap):
-            src, counts32, slot, n = _agg.seg_unique_pallas(
-                new, sv, cap, block=block, interpret=interpret
-            )
-        else:
-            src, counts32, slot, n = _agg.seg_unique_ref(new, sv, cap)
+        src, counts32, slot, n = _agg.seg_unique_pallas(
+            new, sv, cap, block=block, interpret=interpret
+        )
         uvalid = jnp.arange(cap, dtype=jnp.int32) < jnp.minimum(n, cap)
         uniq = jnp.where(uvalid[:, None], sc[jnp.minimum(src, b - 1)], 0)
         if weights is None:

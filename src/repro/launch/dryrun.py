@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = os.environ.get("REPRO_DRYRUN_XLA_FLAGS", "--xla_force_host_platform_device_count=512")
-
 """Multi-pod dry-run (assignment deliverable e).
 
 For every (architecture x shape) cell and mesh, AOT-lower and compile the
@@ -12,9 +9,12 @@ bytes for §Dry-run and §Roofline. No arrays are ever allocated.
 Run:  PYTHONPATH=src python -m repro.launch.dryrun [--arch A] [--shape S]
       [--multi-pod] [--all]
 Results accumulate in benchmarks/dryrun_results.json (incremental cache).
+Run as a script it forces 512 host devices (``REPRO_DRYRUN_XLA_FLAGS``
+overrides); importing the module changes no environment.
 """
 import argparse
 import json
+import os
 import time
 import traceback
 
@@ -447,4 +447,7 @@ def main():
 
 
 if __name__ == "__main__":
+    os.environ["XLA_FLAGS"] = os.environ.get(
+        "REPRO_DRYRUN_XLA_FLAGS", "--xla_force_host_platform_device_count=512"
+    )
     main()

@@ -139,6 +139,20 @@ def test_radix_sort_codes_matches_sort_codes():
     np.testing.assert_array_equal(np.sort(np.asarray(order)), np.arange(500))
 
 
+def test_radix_sort_codes_multi_block():
+    """Digit cursors carry across grid steps (3,000 rows, 1,024 a step)."""
+    from repro.kernels.aggregate import sort_codes
+
+    rng = np.random.default_rng(6)
+    codes = jnp.asarray(_fake_codes(rng, 3000))
+    valid = jnp.asarray(rng.random(3000) < 0.7)
+    sc, sv, _ = sort_codes(codes, valid)
+    rc, rv, _ = radix_bin.radix_sort_codes(codes, valid, block=1024,
+                                           interpret=True)
+    np.testing.assert_array_equal(np.asarray(rc), np.asarray(sc))
+    np.testing.assert_array_equal(np.asarray(rv), np.asarray(sv))
+
+
 # ---------------------------------------------------------------------------
 # forced-decision matrix: bit-identical results across every table choice
 # ---------------------------------------------------------------------------
@@ -355,19 +369,20 @@ def test_small_graph_skips_pilot(monkeypatch):
 
 
 def test_probe_failure_falls_back_static(monkeypatch):
+    """A failing probe propagates out of ``resolve`` (no static fallback
+    table hides it) and leaves nothing cached."""
     monkeypatch.setattr(
-        costmodel, "_calibrate",
+        costmodel, "_time_us",
         lambda *a, **k: (_ for _ in ()).throw(RuntimeError("probe boom")),
     )
     g = to_device(_cal_graph(20))
     costmodel.clear_cache()
-    _, t = costmodel.resolve(
-        EngineConfig(cost_model_min_edges=0), g, MotifsApp(max_size=3),
-        "serial",
-    )
-    assert t.source == "static:probe-error"
-    for knob in costmodel.DECIDED_KNOBS:
-        assert getattr(t, knob) is not None
+    with pytest.raises(RuntimeError, match="probe boom"):
+        costmodel.resolve(
+            EngineConfig(cost_model_min_edges=0), g, MotifsApp(max_size=3),
+            "serial",
+        )
+    assert not costmodel._PROCESS_CACHE
     costmodel.clear_cache()
 
 

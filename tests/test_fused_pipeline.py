@@ -49,6 +49,18 @@ def test_stream_compact_matches_ref(b, out_cap):
     assert (np.asarray(idx_k[valid:]) == 0).all()
 
 
+@pytest.mark.parametrize("b,block", [(5000, 1024), (70000, 8192)])
+def test_prefix_sum_carries_across_blocks(b, block):
+    """The scan's running total crosses grid steps exactly."""
+    from repro.kernels.compact import prefix_sum_pallas
+
+    keep = np.random.default_rng(b).random(b) < 0.4
+    incl, total = prefix_sum_pallas(jnp.asarray(keep), block=block,
+                                    interpret=True)
+    np.testing.assert_array_equal(np.asarray(incl), np.cumsum(keep))
+    assert int(total) == int(keep.sum())
+
+
 @pytest.mark.parametrize("keep", [
     np.zeros(100, bool),          # nothing kept
     np.ones(100, bool),           # everything kept

@@ -46,6 +46,34 @@ def test_resolve_interpret_explicit_passthrough():
     assert dispatch.resolve_interpret(False) is False
 
 
+def test_resolve_interpret_refuses_interpreter_on_tpu(monkeypatch):
+    """On a TPU backend the kernels compile; asking for the interpreter is
+    an error, not a silent slow path."""
+    monkeypatch.setattr(dispatch.jax, "default_backend", lambda: "tpu")
+    assert dispatch.resolve_interpret(None) is False
+    assert dispatch.resolve_interpret(False) is False
+    with pytest.raises(ValueError, match="interpret"):
+        dispatch.resolve_interpret(True)
+
+
+def test_run_records_kernel_routes(monkeypatch):
+    """RunStats.kernel_routes names each kernel a run traced and how it
+    ran, including a VMEM-size decline to the jnp route."""
+    g = G.random_labeled(30, 70, n_labels=2, seed=11)
+    cfg = EngineConfig(use_pallas=True, compact_kernel=True,
+                       pallas_interpret=True, max_steps=3)
+    jax.clear_caches()
+    res = run(g, MotifsApp(max_size=3), cfg)
+    assert res.stats.kernel_routes["canonical_check"] == ["interpret"]
+    assert res.stats.kernel_routes["prefix_sum"] == ["interpret"]
+    monkeypatch.setattr(cc_ops, "VMEM_BITMAP_LIMIT", 0)
+    jax.clear_caches()
+    res = run(g, CliquesApp(max_size=3), cfg)
+    assert res.stats.kernel_routes["canonical_check"] == [
+        "jnp:bitmap-exceeds-vmem"
+    ]
+
+
 def test_resolve_interpret_auto_matches_backend():
     expected = jax.default_backend() not in dispatch.COMPILED_BACKENDS
     assert dispatch.resolve_interpret(None) is expected
@@ -195,8 +223,8 @@ def test_engine_pallas_equivalence_paper_graph():
 
 
 def test_distributed_pallas_equivalence():
-    """pallas_call inside the shard_map worker (needs check_rep=False —
-    regression for the _shard_map_pallas_ok dispatch)."""
+    """pallas_call inside the shard_map worker (needs check_vma=False:
+    a pallas_call has no varying-axes rule)."""
     from repro.core.distributed import DistConfig, run_distributed
 
     mesh = jax.make_mesh((1,), ("data",))
