@@ -1,0 +1,10 @@
+"""Device: share of the traced jobs' window in which no operation ran on
+the chip (1 - busy / window, busy as the union of the operations'
+intervals)."""
+
+
+def read(ctx):
+    t = ctx.trace
+    if t is None or t["window_s"] <= 0 or t["busy_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - t["busy_s"] / t["window_s"])
