@@ -211,7 +211,7 @@ def aggregate_rows(
         canon_slot, verts_canon = map_to_canonical_positions(
             table, inv, np.asarray(local_verts)
         )
-        verts_canon = np.asarray(verts_canon)
+        verts_canon = obs.to_host(verts_canon)
         kmax = verts_canon.shape[1]
         bm = np.zeros((pc, kmax, g_n_vertices), dtype=bool)
         ok = (verts_canon >= 0) & (canon_slot[:, None] >= 0)
@@ -440,7 +440,7 @@ class DeviceLevel1:
         sat = self._sat if self._sat is not None else jnp.zeros((), bool)
         stack = jnp.stack([jnp.asarray(x, jnp.int32) for x in
                            (self._merge_ns + [n])])
-        flags = np.asarray(_finish_flags(u, c, uv, stack, corrupt, sat))
+        flags = obs.to_host(_finish_flags(u, c, uv, stack, corrupt, sat))
         nbytes = flags.nbytes
         self.observed_n = n_final = int(flags[0])
         max_n = int(flags[1])
@@ -457,7 +457,7 @@ class DeviceLevel1:
             # distinct total rode the scalar read, no extra sync
             u, c, uv, cap, n = self._finalize(_next_pow2(max_n))
             stack = jnp.stack([jnp.asarray(self._merge_ns[-1], jnp.int32)])
-            flags = np.asarray(
+            flags = obs.to_host(
                 _finish_flags(u, c, uv, stack, jnp.zeros((), bool),
                               jnp.zeros((), bool))
             )
@@ -491,13 +491,13 @@ def drain_distinct(u_dev, c_dev, n: int, w1_used: bool, w2_used: bool,
     encoding), counts narrowed to int32 when they fit. Returns
     ``(uniq (n, 3) int64, counts (n,) int64, bytes_transferred)``."""
     cols = [0] + ([1] if w1_used else []) + ([2] if w2_used else [])
-    packed = np.asarray(
+    packed = obs.to_host(
         agg_kernel.pack_codes_u32(u_dev[:n][:, jnp.asarray(cols)])
     )
     uniq = np.zeros((n, 3), np.int64)
     uniq[:, cols] = agg_kernel.unpack_codes_u32(packed)
     cdev = c_dev[:n]
-    counts = np.asarray(cdev.astype(jnp.int32) if fit32 else cdev)
+    counts = obs.to_host(cdev.astype(jnp.int32) if fit32 else cdev)
     return uniq, counts.astype(np.int64), packed.nbytes + counts.nbytes
 
 
@@ -650,14 +650,14 @@ def device_level2(u, c, uv, cap: int, n_final: int, quick_codes: np.ndarray,
         u, c, uv, cap, nvs, with_domains, use_kernel, interpret, method
     )
     q = int(n_final)
-    pc = int(cn_d)
-    sigma = np.asarray(sigma_d[:q], dtype=np.int32)
-    q2c = np.asarray(q2c_d[:q], dtype=np.int32)
-    cu = np.asarray(cu_d[:pc], dtype=np.int64)
-    cc = np.asarray(cc_d[:pc], dtype=np.int64)
-    canon_rows = np.asarray(canon_d[:q], dtype=np.int64)
+    pc = int(obs.to_host(cn_d))
+    sigma = obs.to_host(sigma_d[:q], np.int32)
+    q2c = obs.to_host(q2c_d[:q], np.int32)
+    cu = obs.to_host(cu_d[:pc], np.int64)
+    cc = obs.to_host(cc_d[:pc], np.int64)
+    canon_rows = obs.to_host(canon_d[:q], np.int64)
     if with_domains:
-        orbits = np.asarray(rep_d[:pc], dtype=np.int32)
+        orbits = obs.to_host(rep_d[:pc], np.int32)
     else:
         orbits = np.tile(
             np.arange(pattern_lib.MAX_PATTERN_VERTICES, dtype=np.int32),

@@ -30,7 +30,7 @@ from repro.kernels import aggregate as aggregate_kernel_lib
 from repro.kernels import compact as compact_kernel_lib
 from repro.kernels import gather as gather_kernel_lib
 from repro.kernels.canonical_check import ops as cc_ops
-from repro.kernels.dispatch import note_route
+from repro.kernels.dispatch import device_scope, note_route
 
 
 class TileView(NamedTuple):
@@ -199,65 +199,72 @@ def expand_vertex(
     """
     if use_pallas and fused:
         if cc_ops.fits_vmem_fused(g):
-            return _expand_vertex_fused(g, members, n_valid, interpret)
+            with device_scope("expand_canonical"):
+                return _expand_vertex_fused(g, members, n_valid, interpret)
         note_route("expand_canonical", "jnp:tables-exceed-vmem")
-    c, k = members.shape
-    d = g.max_degree
-    pos = jnp.arange(k)[None, :]
-    member_ok = pos < n_valid[:, None]                      # (C, k)
+    with device_scope("gather"):
+        c, k = members.shape
+        d = g.max_degree
+        pos = jnp.arange(k)[None, :]
+        member_ok = pos < n_valid[:, None]                      # (C, k)
 
-    tiled = isinstance(g, TileView)
-    if tiled:
-        # partitioned path: members are halo-resident by construction, so
-        # every member-rooted lookup goes through tile ranks while ids stay
-        # global (columns of adj_t are global; see TileView)
-        ranks, in_tile = g.rank(members)
-        mrow = jnp.where(member_ok & in_tile, ranks, -1)     # (C, k)
-        cand = jnp.where(
-            (member_ok & in_tile)[:, :, None], g.nbr_t[ranks], -1
-        )                                                    # (C, k, D)
-    else:
-        safe = jnp.maximum(members, 0)
-        cand = jnp.where(member_ok[:, :, None], g.nbr[safe], -1)  # (C, k, D)
-    slot_ok = cand >= 0
+        tiled = isinstance(g, TileView)
+        if tiled:
+            # partitioned path: members are halo-resident by construction, so
+            # every member-rooted lookup goes through tile ranks while ids stay
+            # global (columns of adj_t are global; see TileView)
+            ranks, in_tile = g.rank(members)
+            mrow = jnp.where(member_ok & in_tile, ranks, -1)     # (C, k)
+            cand = jnp.where(
+                (member_ok & in_tile)[:, :, None], g.nbr_t[ranks], -1
+            )                                                    # (C, k, D)
+        else:
+            safe = jnp.maximum(members, 0)
+            cand = jnp.where(
+                member_ok[:, :, None], g.nbr[safe], -1
+            )                                                    # (C, k, D)
+        slot_ok = cand >= 0
 
-    # not already a member of the embedding
-    is_member = (cand[:, :, :, None] == members[:, None, None, :]).any(-1)
+        # not already a member of the embedding
+        is_member = (cand[:, :, :, None] == members[:, None, None, :]).any(-1)
 
-    # first-occurrence dedup: drop if an *earlier* member is adjacent to cand.
-    if tiled:
-        adj_em = bitset.test_bit(
-            g.adj_t, mrow[:, :, None, None], cand[:, None, :, :]
+        # first-occurrence dedup: drop if an *earlier* member is adjacent
+        # to cand.
+        if tiled:
+            adj_em = bitset.test_bit(
+                g.adj_t, mrow[:, :, None, None], cand[:, None, :, :]
+            )
+        else:
+            adj_em = g.is_edge(members[:, :, None, None], cand[:, None, :, :])
+        adj_em = adj_em & member_ok[:, :, None, None]    # (C, k_m, k_i, D)
+        earlier = (
+            jnp.arange(k)[None, :, None, None]
+            < jnp.arange(k)[None, None, :, None]
         )
-    else:
-        adj_em = g.is_edge(members[:, :, None, None], cand[:, None, :, :])
-    adj_em = adj_em & member_ok[:, :, None, None]           # (C, k_m, k_i, D)
-    earlier = (
-        jnp.arange(k)[None, :, None, None] < jnp.arange(k)[None, None, :, None]
-    )
-    seen_earlier = (adj_em & earlier).any(axis=1)           # (C, k_i, D)
+        seen_earlier = (adj_em & earlier).any(axis=1)           # (C, k_i, D)
 
-    valid = slot_ok & ~is_member & ~seen_earlier
+        valid = slot_ok & ~is_member & ~seen_earlier
 
-    flat_cand = cand.reshape(c * k * d)
-    flat_rows = jnp.repeat(jnp.arange(c, dtype=jnp.int32), k * d)
-    flat_valid = valid.reshape(c * k * d)
+        flat_cand = cand.reshape(c * k * d)
+        flat_rows = jnp.repeat(jnp.arange(c, dtype=jnp.int32), k * d)
+        flat_valid = valid.reshape(c * k * d)
 
-    if tiled:
-        canon = cc_ops.canonical_check_tiles(
-            members[flat_rows], mrow[flat_rows], n_valid[flat_rows],
-            flat_cand, g.adj_t,
-            use_pallas=use_pallas, interpret=interpret,
-        )
-    elif use_pallas:
-        canon = cc_ops.canonical_check(
-            g, members[flat_rows], n_valid[flat_rows], flat_cand,
-            mode="vertex", interpret=interpret,
-        )
-    else:
-        canon = canonical.vertex_check(
-            g, members[flat_rows], n_valid[flat_rows], flat_cand
-        )
+    with device_scope("canonical_check"):
+        if tiled:
+            canon = cc_ops.canonical_check_tiles(
+                members[flat_rows], mrow[flat_rows], n_valid[flat_rows],
+                flat_cand, g.adj_t,
+                use_pallas=use_pallas, interpret=interpret,
+            )
+        elif use_pallas:
+            canon = cc_ops.canonical_check(
+                g, members[flat_rows], n_valid[flat_rows], flat_cand,
+                mode="vertex", interpret=interpret,
+            )
+        else:
+            canon = canonical.vertex_check(
+                g, members[flat_rows], n_valid[flat_rows], flat_cand
+            )
     keep = flat_valid & canon
     return Expansion(
         rows=flat_rows,
@@ -302,55 +309,59 @@ def expand_edge(
     slot holds the same vertex (whole incident list already enumerated) or
     the candidate's other endpoint (edge enumerated from the other side).
     """
-    c, k = members.shape
-    d = g.max_degree
-    k2 = 2 * k
-    safe = jnp.maximum(members, 0)
-    pos = jnp.arange(k)[None, :]
-    member_ok = pos < n_valid[:, None]                       # (C, k)
+    with device_scope("gather"):
+        c, k = members.shape
+        d = g.max_degree
+        k2 = 2 * k
+        safe = jnp.maximum(members, 0)
+        pos = jnp.arange(k)[None, :]
+        member_ok = pos < n_valid[:, None]                       # (C, k)
 
-    verts = g.edge_uv[safe].reshape(c, k2)                   # (C, 2k)
-    vert_ok = jnp.repeat(member_ok, 2, axis=1)               # (C, 2k)
-    verts = jnp.where(vert_ok, verts, -1)
+        verts = g.edge_uv[safe].reshape(c, k2)                   # (C, 2k)
+        vert_ok = jnp.repeat(member_ok, 2, axis=1)               # (C, 2k)
+        verts = jnp.where(vert_ok, verts, -1)
 
-    if isinstance(g, TileView):
-        # partitioned path: member-edge endpoints are the halo, so their
-        # incident-edge / neighbour rows come from the gathered tiles
-        ranks, in_tile = g.rank(verts)
-        ok3 = (vert_ok & in_tile)[:, :, None]
-        cand = jnp.where(ok3, g.nbr_eid_t[ranks], -1)        # (C, 2k, D)
-        other = jnp.where(ok3, g.nbr_t[ranks], -1)           # (C, 2k, D)
-    else:
-        safe_v = jnp.maximum(verts, 0)
-        cand = jnp.where(vert_ok[:, :, None], g.nbr_eid[safe_v], -1)
-        other = jnp.where(vert_ok[:, :, None], g.nbr[safe_v], -1)
-    slot_ok = cand >= 0
+        if isinstance(g, TileView):
+            # partitioned path: member-edge endpoints are the halo, so their
+            # incident-edge / neighbour rows come from the gathered tiles
+            ranks, in_tile = g.rank(verts)
+            ok3 = (vert_ok & in_tile)[:, :, None]
+            cand = jnp.where(ok3, g.nbr_eid_t[ranks], -1)        # (C, 2k, D)
+            other = jnp.where(ok3, g.nbr_t[ranks], -1)           # (C, 2k, D)
+        else:
+            safe_v = jnp.maximum(verts, 0)
+            cand = jnp.where(vert_ok[:, :, None], g.nbr_eid[safe_v], -1)
+            other = jnp.where(vert_ok[:, :, None], g.nbr[safe_v], -1)
+        slot_ok = cand >= 0
 
-    is_member = (cand[:, :, :, None] == members[:, None, None, :]).any(-1)
+        is_member = (cand[:, :, :, None] == members[:, None, None, :]).any(-1)
 
-    slot_idx = jnp.arange(k2)
-    earlier = slot_idx[None, :, None, None] < slot_idx[None, None, :, None]
-    same_vertex = verts[:, :, None, None] == verts[:, None, :, None]
-    hits_other = verts[:, :, None, None] == other[:, None, :, :]
-    dup = (same_vertex & earlier).any(axis=1) | (hits_other & earlier).any(axis=1)
+        slot_idx = jnp.arange(k2)
+        earlier = slot_idx[None, :, None, None] < slot_idx[None, None, :, None]
+        same_vertex = verts[:, :, None, None] == verts[:, None, :, None]
+        hits_other = verts[:, :, None, None] == other[:, None, :, :]
+        dup = ((same_vertex & earlier).any(axis=1)
+               | (hits_other & earlier).any(axis=1))
 
-    valid = slot_ok & ~is_member & ~dup
+        valid = slot_ok & ~is_member & ~dup
 
-    flat_cand = cand.reshape(c * k2 * d)
-    flat_rows = jnp.repeat(jnp.arange(c, dtype=jnp.int32), k2 * d)
-    flat_valid = valid.reshape(c * k2 * d)
+        flat_cand = cand.reshape(c * k2 * d)
+        flat_rows = jnp.repeat(jnp.arange(c, dtype=jnp.int32), k2 * d)
+        flat_valid = valid.reshape(c * k2 * d)
 
-    if use_pallas:
-        # routed through the kernel dispatch even though edge mode currently
-        # always resolves to the jnp check (see ops.py dispatch rules).
-        canon = cc_ops.canonical_check(
-            g, members[flat_rows], n_valid[flat_rows], flat_cand,
-            mode="edge", interpret=interpret,
-        )
-    else:
-        canon = canonical.edge_check(
-            g, members[flat_rows], n_valid[flat_rows], flat_cand
-        )
+    with device_scope("canonical_check"):
+        if use_pallas:
+            # routed through the kernel dispatch even though edge mode
+            # currently always resolves to the jnp check (see ops.py
+            # dispatch rules).
+            canon = cc_ops.canonical_check(
+                g, members[flat_rows], n_valid[flat_rows], flat_cand,
+                mode="edge", interpret=interpret,
+            )
+        else:
+            canon = canonical.edge_check(
+                g, members[flat_rows], n_valid[flat_rows], flat_cand
+            )
     keep = flat_valid & canon
     return Expansion(
         rows=flat_rows,
@@ -487,13 +498,19 @@ def fused_chunk_step(
     the :class:`TileView`. The tile capacity is a static function of the
     chunk shape, so the output contract (and the engines' drain protocol)
     is unchanged. A pre-built ``TileView`` is also accepted (the shard-map
-    worker builds its own view with collectives)."""
+    worker builds its own view with collectives).
+
+    Every stage runs under its own ``device_scope`` (``tile_gather``,
+    ``gather``, ``canonical_check`` or the fused ``expand_canonical``,
+    ``app_filter``, ``compact``, ``pack``), so a profiler trace ties each
+    device operation of the program to its stage."""
     if isinstance(g, PartitionedGraph):
-        g = build_tile_view(
-            g, members, n_valid, mode,
-            use_pallas=use_pallas, compact_kernel=compact_kernel,
-            interpret=interpret,
-        )
+        with device_scope("tile_gather"):
+            g = build_tile_view(
+                g, members, n_valid, mode,
+                use_pallas=use_pallas, compact_kernel=compact_kernel,
+                interpret=interpret,
+            )
     if mode == "vertex":
         exp = expand_vertex(
             g, members, n_valid,
@@ -505,44 +522,52 @@ def fused_chunk_step(
         )
     keep = exp.keep
     if app is not None:
-        keep = keep & app.filter(g, members, n_valid, exp.rows, exp.cand)
-    children, count = compact(
-        members, exp, keep, out_cap,
-        use_kernel=compact_kernel, interpret=interpret,
-    )
-    if with_patterns or with_aggregates:
-        child_k = members.shape[1] + 1
-        child_nv = jnp.where(
-            jnp.arange(out_cap) < count, child_k, 0
-        ).astype(jnp.int32)
-        qp = (
-            pattern_lib.quick_pattern_vertex(g, children, child_nv)
-            if mode == "vertex"
-            else pattern_lib.quick_pattern_edge(g, children, child_nv)
+        with device_scope("app_filter"):
+            keep = keep & app.filter(g, members, n_valid, exp.rows, exp.cand)
+    with device_scope("compact"):
+        children, count = compact(
+            members, exp, keep, out_cap,
+            use_kernel=compact_kernel, interpret=interpret,
         )
-        if with_aggregates:
-            uniq, ucounts, _, n_uniq, _ = aggregate_kernel_lib.bin_rows(
-                qp.codes, child_nv > 0, min(out_cap, agg_qcap),
-                use_kernel=aggregate_kernel, interpret=interpret,
-                method=aggregate_bin,
-            )
-            # the partial crosses chunks as int32: SATURATE at the I32_SAT
-            # sentinel instead of wrapping — fold_partial detects the
-            # sentinel and the step re-folds wide (DESIGN.md §13)
-            ucounts32 = jnp.minimum(
-                ucounts, jnp.int64(aggregate_kernel_lib.I32_SAT)
+    with device_scope("pack"):
+        if with_patterns or with_aggregates:
+            child_k = members.shape[1] + 1
+            child_nv = jnp.where(
+                jnp.arange(out_cap) < count, child_k, 0
             ).astype(jnp.int32)
-            return (children, count, uniq, ucounts32,
-                    n_uniq, exp.n_generated, exp.n_canonical)
-        codes = qp.codes
-        # only FSM's min-image domains read the local-vertex table; when
-        # unused, dropping it from the outputs lets XLA DCE its scatter
-        local_verts = (
-            qp.local_verts
-            if with_local_verts
-            else jnp.zeros((0, pattern_lib.MAX_PATTERN_VERTICES), jnp.int32)
-        )
-    else:
-        codes = jnp.zeros((0, 3), jnp.int64)
-        local_verts = jnp.zeros((0, pattern_lib.MAX_PATTERN_VERTICES), jnp.int32)
-    return children, count, codes, local_verts, exp.n_generated, exp.n_canonical
+            qp = (
+                pattern_lib.quick_pattern_vertex(g, children, child_nv)
+                if mode == "vertex"
+                else pattern_lib.quick_pattern_edge(g, children, child_nv)
+            )
+            if with_aggregates:
+                uniq, ucounts, _, n_uniq, _ = aggregate_kernel_lib.bin_rows(
+                    qp.codes, child_nv > 0, min(out_cap, agg_qcap),
+                    use_kernel=aggregate_kernel, interpret=interpret,
+                    method=aggregate_bin,
+                )
+                # the partial crosses chunks as int32: SATURATE at the I32_SAT
+                # sentinel instead of wrapping — fold_partial detects the
+                # sentinel and the step re-folds wide (DESIGN.md §13)
+                ucounts32 = jnp.minimum(
+                    ucounts, jnp.int64(aggregate_kernel_lib.I32_SAT)
+                ).astype(jnp.int32)
+                return (children, count, uniq, ucounts32,
+                        n_uniq, exp.n_generated, exp.n_canonical)
+            codes = qp.codes
+            # only FSM's min-image domains read the local-vertex table; when
+            # unused, dropping it from the outputs lets XLA DCE its scatter
+            local_verts = (
+                qp.local_verts
+                if with_local_verts
+                else jnp.zeros(
+                    (0, pattern_lib.MAX_PATTERN_VERTICES), jnp.int32
+                )
+            )
+        else:
+            codes = jnp.zeros((0, 3), jnp.int64)
+            local_verts = jnp.zeros(
+                (0, pattern_lib.MAX_PATTERN_VERTICES), jnp.int32
+            )
+        return (children, count, codes, local_verts,
+                exp.n_generated, exp.n_canonical)

@@ -2,17 +2,20 @@
 
 The façade every runtime layer imports as ``from repro.core import obs``:
 
-  * ``obs.span("expand", step=k, ...)`` — nested host phase spans
-    (a shared nullcontext when no tracer is installed: zero allocation,
-    zero device syncs on the disabled path);
+  * ``obs.span("expand", step=k, ...)`` — nested host phase spans, each
+    also a ``jax.profiler.TraceAnnotation`` (a shared nullcontext when no
+    tracer is installed: zero allocation, zero device syncs on the
+    disabled path);
   * ``obs.count(st, "bytes_to_host", n)`` / ``obs.set_stat(...)`` — THE
     write path for StepStats counters, bit-identical to the inline
     mutations it replaced, mirrored into the metrics registry while
     observing;
   * ``obs.fence(*trees)`` — blocking phase boundaries, ONLY under
     ``trace_sync=True``;
-  * ``obs.annotate("fused_chunk")`` — ``jax.profiler.TraceAnnotation``
-    device/host timeline alignment while traced;
+  * ``obs.to_host(x)`` — a counted blocking device→host read
+    (``StepStats.n_device_reads``);
+  * ``obs.totals()`` — cumulative counters of every run the process
+    completed, for an operator to scrape;
   * :class:`RunObserver` — the per-run bundle the loop drives (install,
     per-step counters + progress log, Chrome-trace/JSONL export).
 
@@ -31,9 +34,13 @@ from repro.core.obs.export import (              # noqa: F401
 from repro.core.obs.metrics import (             # noqa: F401
     MetricsRegistry,
     count,
+    fold_run,
     gauge,
+    read_count,
     sample_device_memory,
     set_stat,
+    to_host,
+    totals,
 )
 from repro.core.obs.metrics import (             # noqa: F401
     current as current_metrics,
@@ -44,7 +51,6 @@ from repro.core.obs.metrics import (             # noqa: F401
 from repro.core.obs.tracer import (              # noqa: F401
     Span,
     Tracer,
-    annotate,
     fence,
     probe_time,
     span,

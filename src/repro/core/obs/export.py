@@ -34,14 +34,6 @@ PHASES = (
 )
 
 _PID = os.getpid()
-_SEQ_LOCK = threading.Lock()
-_SEQ = [0]
-
-
-def _next_seq() -> int:
-    with _SEQ_LOCK:
-        _SEQ[0] += 1
-        return _SEQ[0]
 
 
 # -- Chrome trace-event rendering ---------------------------------------------
@@ -264,17 +256,14 @@ class RunObserver:
         self._finished = False
         if self.enabled:
             self.registry = metrics_lib.MetricsRegistry()
-            on_close = None
+            self.tracer = tracer_lib.Tracer(sync=bool(config.trace_sync))
             if config.trace_dir is not None:
                 base = os.path.join(
-                    config.trace_dir, f"run-{_PID}-{_next_seq():04d}"
+                    config.trace_dir, f"run-{_PID}-{self.tracer.run_id:04d}"
                 )
                 self.trace_path = base + ".trace.json"
                 self._jsonl = _JsonlWriter(base + ".events.jsonl")
-                on_close = self._span_closed
-            self.tracer = tracer_lib.Tracer(
-                sync=bool(config.trace_sync), on_close=on_close
-            )
+                self.tracer.on_close = self._span_closed
 
     def _span_closed(self, sp: tracer_lib.Span) -> None:
         # hot path (fires inside the superstep span): a buffered append —

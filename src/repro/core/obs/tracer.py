@@ -24,14 +24,23 @@ Design constraints, in order:
 Timestamps are microseconds since the tracer's epoch (``perf_counter``
 based — monotonic, sub-µs resolution), the unit Chrome trace events use
 natively.
+
+Every span is also a ``jax.profiler.TraceAnnotation`` of the same name, so
+under a ``jax.profiler`` capture the program's spans sit on the device
+trace's clock, on whichever thread opened them (TensorBoard, Perfetto).
+Each annotation carries the tracer's ``run`` id and the innermost
+enclosing ``step``: all spans of one run share the id.
 """
 from __future__ import annotations
 
 import contextlib
 import dataclasses
+import itertools
 import threading
 import time
 from typing import Any, Callable, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 
 @dataclasses.dataclass
@@ -56,6 +65,10 @@ class CounterSample:
     values: Dict[str, float]
 
 
+#: process-wide run ids: one per tracer, so per traced run
+_RUN_IDS = itertools.count(1)
+
+
 class Tracer:
     """Collects spans + counter samples for one (or more) mining runs."""
 
@@ -63,6 +76,7 @@ class Tracer:
                  on_close: Optional[Callable[[Span], None]] = None) -> None:
         self.sync = bool(sync)
         self.on_close = on_close
+        self.run_id = next(_RUN_IDS)
         self.epoch = time.perf_counter()
         self.spans: List[Span] = []
         self.counters: List[CounterSample] = []
@@ -93,12 +107,17 @@ class Tracer:
     # -- recording -----------------------------------------------------------
     @contextlib.contextmanager
     def span(self, name: str, **attrs):
-        stack = self._stack()
-        parent = stack[-1] if stack else None
-        stack.append(name)
+        stack = self._stack()               # (name, step) per open span
+        parent, step = stack[-1] if stack else (None, None)
+        step = attrs.get("step", step)
+        stack.append((name, step))
+        meta = {"run": self.run_id}
+        if step is not None:
+            meta["step"] = int(step)
         t0 = self._now()
         try:
-            yield
+            with TraceAnnotation(name, **meta):
+                yield
         finally:
             t1 = self._now()
             stack.pop()
@@ -195,16 +214,3 @@ def probe_time(fn, *args) -> float:
     jax.block_until_ready(fn(*args))
     return time.perf_counter() - t0
 
-
-def annotate(name: str):
-    """A ``jax.profiler.TraceAnnotation`` aligning the device profiler's
-    timeline with the host span taxonomy — created only while a tracer is
-    installed (the disabled path must not touch profiler machinery)."""
-    if _TRACER is None:
-        return _NULL
-    import jax
-
-    try:
-        return jax.profiler.TraceAnnotation(name)
-    except Exception:  # pragma: no cover - profiler backend unavailable
-        return _NULL

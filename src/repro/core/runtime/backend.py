@@ -108,7 +108,8 @@ class ExecutionBackend(abc.ABC):
         ):
             codes, lv = carried
         else:
-            codes, lv = self.quick_codes(blocks, size)
+            with obs.span("aggregate.bin"):
+                codes, lv = self.quick_codes(blocks, size)
         obs.count(st, "bytes_to_host", codes.nbytes + lv.nbytes)
         return self.aggregate(codes, lv, st)
 

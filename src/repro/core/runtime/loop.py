@@ -202,6 +202,9 @@ class SuperstepRuntime:
             size, first_step = state.size, state.step
         if decisions is not None:
             result.stats.cost_model = decisions.as_dict()
+        #: supersteps a resumed run inherited: only this run's own steps
+        #: fold into the process totals
+        n_prior = len(result.stats.steps)
 
         #: fused mode: (codes, local_verts) of the sealed frontier, carried
         #: from the previous superstep's chunk programs — the next
@@ -252,7 +255,7 @@ class SuperstepRuntime:
                     if app.wants_patterns:
                         with obs.span(
                             "aggregate", step=step, frontier=st.n_frontier
-                        ), obs.annotate("aggregate"):
+                        ):
                             self.failed_phase = "aggregate"
                             faults_lib.trip(plan, "aggregate", step)
                             agg, canon_slot = backend.aggregate_step(
@@ -343,9 +346,7 @@ class SuperstepRuntime:
                     else:
                         # ---- expansion: children appended to the store as
                         # produced ---------------------------------------
-                        with obs.span(
-                            "expand", step=step, frontier=b_live
-                        ), obs.annotate("expand"):
+                        with obs.span("expand", step=step, frontier=b_live):
                             self.failed_phase = "expand"
                             faults_lib.trip(plan, "expand", step)
                             carried = backend.expand(store, blocks, size, st)
@@ -375,9 +376,7 @@ class SuperstepRuntime:
                             and store.n_rows
                             and step % max(config.checkpoint_every, 1) == 0
                         ):
-                            with obs.span(
-                                "checkpoint", step=step
-                            ), obs.annotate("checkpoint"):
+                            with obs.span("checkpoint", step=step):
                                 self.failed_phase = "checkpoint"
                                 faults_lib.trip(plan, "checkpoint", step)
                                 obs.set_stat(
@@ -412,6 +411,7 @@ class SuperstepRuntime:
                 time.perf_counter() - t_start
             )
             backend.finalize(result.stats)
+            obs.fold_run(result.stats.steps[n_prior:])
             result.stats.kernel_routes = {
                 k: sorted(v) for k, v in sorted(routes.items())
             }

@@ -23,6 +23,7 @@ the host reference path (``aggregation.aggregate_rows``).
 """
 from __future__ import annotations
 
+import functools
 import time
 from typing import List, Optional
 
@@ -46,6 +47,20 @@ from repro.core.store import FrontierStore, make_store
 #: O(chunks / window) per superstep — 1 + pilot for any step under ~32
 #: chunks.
 _DRAIN_WINDOW = 32
+
+
+def _charges_reads(hook):
+    """Charge the blocking device reads (``obs.to_host``) a backend hook
+    makes to ``st.n_device_reads`` (``st``: the hook's last argument):
+    a host-side tally, no device work."""
+    @functools.wraps(hook)
+    def charged(self, *args):
+        r0 = obs.read_count()
+        try:
+            return hook(self, *args)
+        finally:
+            obs.count(args[-1], "n_device_reads", obs.read_count() - r0)
+    return charged
 
 
 class SerialBackend(ExecutionBackend):
@@ -161,8 +176,8 @@ class SerialBackend(ExecutionBackend):
                 self.g, self.app.mode, self._wave_dev[wi],
                 jnp.full((len(w),), size, dtype=jnp.int32),
             )
-            codes_parts.append(np.asarray(qp.codes))
-            lv_parts.append(np.asarray(qp.local_verts))
+            codes_parts.append(obs.to_host(qp.codes))
+            lv_parts.append(obs.to_host(qp.local_verts))
             if self.config.device_budget_bytes is not None:
                 # SpillStore contract: one budget wave resident at a time —
                 # expansion re-uploads its own wave
@@ -200,6 +215,7 @@ class SerialBackend(ExecutionBackend):
         return agg, canon_slot
 
     # -- device-resident aggregation (DESIGN.md §10) ------------------------
+    @_charges_reads
     def aggregate_step(self, blocks, size, carried, st):
         if not self._device_agg:
             return super().aggregate_step(blocks, size, carried, st)
@@ -212,8 +228,10 @@ class SerialBackend(ExecutionBackend):
             else None
         )
         if lvl1 is None:
-            lvl1 = self._fold_waves(blocks, size)
-        res = lvl1.finish()
+            with obs.span("aggregate.bin"):
+                lvl1 = self._fold_waves(blocks, size)
+        with obs.span("aggregate.drain"):
+            res = lvl1.finish()
         if res is not None and faults_lib.take(
             self.config.faults, "aggregate", st.step, "saturate"
         ):
@@ -236,8 +254,10 @@ class SerialBackend(ExecutionBackend):
                 self._run_qcap, next_pow2(max(lvl1.observed_n, 1))
             )
             self._grow_carried_partials(self._run_qcap)
-            lvl1 = self._fold_waves(blocks, size)
-            res = lvl1.finish()
+            with obs.span("aggregate.bin"):
+                lvl1 = self._fold_waves(blocks, size)
+            with obs.span("aggregate.drain"):
+                res = lvl1.finish()
         uniq, counts_q, nbytes = res
         self._run_qcap = max(self._run_qcap, next_pow2(max(lvl1.observed_n, 1)))
         obs.count(st, "bytes_to_host", nbytes)
@@ -247,8 +267,8 @@ class SerialBackend(ExecutionBackend):
             # boundary, after the next expansion has been enqueued.
             # Eligibility (async_level2_ok) guarantees no pruning reads
             # the table this step, so carrying no _table is safe.
-            obs.annotate("canonicalize_submit")
-            pending = aggregation.submit_level2(uniq, counts_q)
+            with obs.span("canonicalize.submit", n_quick=len(uniq)):
+                pending = aggregation.submit_level2(uniq, counts_q)
             self._lvl1, self._table = lvl1, None
             self._agg_blocks, self._agg_size = blocks, size
             return pending, None
@@ -272,7 +292,8 @@ class SerialBackend(ExecutionBackend):
         obs.count(st, "t_canon", time.perf_counter() - t0)
         pc = len(table.canon_codes)
         if app.wants_domains and pc:
-            bm = self._scatter_domains(lvl1, table, st)
+            with obs.span("aggregate.domains", n_canonical=pc):
+                bm = self._scatter_domains(lvl1, table, st)
             supports = aggregation.min_image_support(
                 bm, table.canon_n_verts, table.canon_orbits
             )
@@ -369,10 +390,11 @@ class SerialBackend(ExecutionBackend):
                 flat, lvl1.batch_slots(i), lvl1.batches[i][1],
                 q2c, si, pc_cap, n,
             )
-        bm = np.asarray(flat[:-1].reshape(pc_cap, kmax, n)[:pc])
+        bm = obs.to_host(flat[:-1].reshape(pc_cap, kmax, n)[:pc])
         obs.count(st, "bytes_to_host", bm.nbytes)
         return bm
 
+    @_charges_reads
     def alpha_rows(self, pk, st):
         """Per-row alpha from the per-pattern verdict: gather the (padded)
         per-quick-slot keep table through the device-resident slot ids —
@@ -398,7 +420,7 @@ class SerialBackend(ExecutionBackend):
         # gather per wave, concatenate on device, drain ONCE — per-wave
         # host round trips would creep back in exactly the spill case
         # (many waves) this pipeline keeps at O(1) drains
-        mask = np.asarray(
+        mask = obs.to_host(
             parts[0] if len(parts) == 1 else jnp.concatenate(parts)
         )
         obs.count(st, "bytes_to_host", mask.nbytes)
@@ -412,6 +434,7 @@ class SerialBackend(ExecutionBackend):
         self._wave_dev = [None] * len(blocks)
         return blocks
 
+    @_charges_reads
     def expand(self, store, blocks, size, st):
         config = self.config
         waves = blocks
@@ -514,6 +537,26 @@ class SerialBackend(ExecutionBackend):
         if "agg" in p:
             programs.retire(*p["agg"][:2])
 
+    def _dispatch(self, st, size, bucket, chunk, n_valid, cap):
+        """One chunk-program call at output capacity ``cap``: records its
+        signature and counts its candidate lanes, the padded (bucket, k, D)
+        table the chip computes (k = 2·size endpoint slots in edge mode)."""
+        self._signatures.add((size, bucket, cap))
+        k = size if self.app.mode == "vertex" else 2 * size
+        obs.count(st, "n_lanes", bucket * k * self.g.max_degree)
+        with obs.span("expand.dispatch", rows=bucket, cap=cap):
+            return self._expand_fn(self.g, chunk, n_valid, out_cap=cap)
+
+    @staticmethod
+    def _fetch(st, x, cnt: int, dtype=None) -> np.ndarray:
+        """Drain the first ``cnt`` rows of a chunk output to the host as a
+        device-side prefix slice: the padding never crosses (same contract
+        as ``store.resolve_rows``)."""
+        with obs.span("expand.fetch", rows=cnt):
+            out = obs.to_host(x[:cnt], dtype)
+        obs.count(st, "rows_to_host", out.nbytes)
+        return out
+
     def _expand_fused(self, store, waves, wave_dev, size, cap, st, lvl1):
         """One *pilot* chunk calibrates the step's output-capacity bucket
         (sync 1 — the PR-2 loop instead discovers capacity growth once per
@@ -528,12 +571,12 @@ class SerialBackend(ExecutionBackend):
         carried child quick codes (``with_patterns``) or pre-binned level-1
         partials into ``lvl1`` (``with_aggregates``, DESIGN.md §10); every
         buffer of the window is retired."""
-        g, expand_fn = self.g, self._expand_fn
-        config, signatures = self.config, self._signatures
+        g, config = self.g, self.config
         with_patterns, with_aggregates = self.with_patterns, self.with_aggregates
-        chunks = list(
-            programs.iter_chunks(waves, wave_dev, config.chunk_size, size)
-        )
+        with obs.span("expand.plan"):
+            chunks = list(
+                programs.iter_chunks(waves, wave_dev, config.chunk_size, size)
+            )
         obs.count(st, "n_chunks", len(chunks))
         if not chunks:
             return None, cap
@@ -550,17 +593,17 @@ class SerialBackend(ExecutionBackend):
 
         # ---- pilot: sync 1 calibrates the capacity bucket for the step --
         _, _, cb0, bucket0, chunk0, n_valid0 = chunks[0]
-        signatures.add((size, bucket0, cap))
-        with obs.annotate("fused_chunk.pilot"):
-            out = self._rec(expand_fn(g, chunk0, n_valid0, out_cap=cap), cap)
-        c0 = int(out["count"])
+        out = self._rec(
+            self._dispatch(st, size, bucket0, chunk0, n_valid0, cap), cap
+        )
+        with obs.span("expand.sync"):
+            c0 = int(obs.to_host(out["count"]))
         obs.count(st, "n_host_syncs", 1)
         if c0 > cap:
             self._retire_outputs(out)
             cap = next_pow2(c0)
-            signatures.add((size, bucket0, cap))
             out = self._rec(                       # count known exact
-                expand_fn(g, chunk0, n_valid0, out_cap=cap), cap
+                self._dispatch(st, size, bucket0, chunk0, n_valid0, cap), cap
             )
         # scale the pilot count to a full bucket for the remaining chunks; a
         # chunk that still overshoots is re-dispatched individually below
@@ -572,12 +615,13 @@ class SerialBackend(ExecutionBackend):
         def drain(pending):
             """One stacked control sync for a window of dispatched chunks,
             exact-cap overflow retries, then fold + retire."""
-            meta = np.asarray(
-                jnp.stack([
-                    s for p, _ in pending
-                    for s in (p["count"], p["ngen"], p["ncanon"])
-                ])
-            ).reshape(-1, 3)
+            with obs.span("expand.sync", chunks=len(pending)):
+                meta = obs.to_host(
+                    jnp.stack([
+                        s for p, _ in pending
+                        for s in (p["count"], p["ngen"], p["ncanon"])
+                    ])
+                ).reshape(-1, 3)
             obs.count(st, "n_host_syncs", 1)
             counts = meta[:, 0]
             obs.count(st, "n_generated", int(meta[:, 1].sum()))
@@ -587,42 +631,43 @@ class SerialBackend(ExecutionBackend):
                     continue
                 self._retire_outputs(p)             # oversubscribed outputs
                 retry_cap = next_pow2(int(counts[i]))
-                signatures.add((size, ch[3], retry_cap))
                 p2 = self._rec(
-                    expand_fn(g, ch[4], ch[5], out_cap=retry_cap), retry_cap
+                    self._dispatch(st, size, ch[3], ch[4], ch[5], retry_cap),
+                    retry_cap,
                 )
                 pending[i] = (p2, ch)
             for i, (p, ch) in enumerate(pending):
                 cnt = int(counts[i])
                 programs.retire(ch[4], ch[5])       # chunk inputs are dead now
                 if cnt:
-                    # device-side prefix slices: the padding never crosses
-                    # to the host (same contract as store.resolve_rows)
-                    store.append(np.asarray(p["children"][:cnt], dtype=np.int32))
+                    rows = self._fetch(st, p["children"], cnt, np.int32)
                     if with_patterns:
-                        codes_parts.append(np.asarray(p["codes"][:cnt]))
-                        lv_parts.append(np.asarray(p["lv"][:cnt]))
-                    if with_aggregates and lvl1 is not None:
-                        # fold the chunk's pre-binned partial; the buffers
-                        # are consumed by the merge (refs dropped there)
-                        u, c, n = p["agg"]
-                        acap = min(p["used_cap"], self._agg_qcap)
-                        lvl1.fold_partial(
-                            u, c, n, acap, cnt,
-                            may_overflow=p["used_cap"] > acap,
-                        )
-                        p.pop("agg")
+                        codes_parts.append(self._fetch(st, p["codes"], cnt))
+                        lv_parts.append(self._fetch(st, p["lv"], cnt))
+                    with obs.span("expand.fold", rows=cnt):
+                        store.append(rows)
+                        if with_aggregates and lvl1 is not None:
+                            # fold the chunk's pre-binned partial; the
+                            # buffers are consumed by the merge (refs
+                            # dropped there)
+                            u, c, n = p["agg"]
+                            acap = min(p["used_cap"], self._agg_qcap)
+                            lvl1.fold_partial(
+                                u, c, n, acap, cnt,
+                                may_overflow=p["used_cap"] > acap,
+                            )
+                            p.pop("agg")
                 self._retire_outputs(p)
 
         pending = [(out, chunks[0])]
         for ch in chunks[1:]:
             _, _, _, bucket_i, chunk_i, n_valid_i = ch
-            signatures.add((size, bucket_i, step_cap))
-            with obs.annotate("fused_chunk"):
-                p = self._rec(
-                    expand_fn(g, chunk_i, n_valid_i, out_cap=step_cap),
-                    step_cap,
-                )
+            p = self._rec(
+                self._dispatch(
+                    st, size, bucket_i, chunk_i, n_valid_i, step_cap
+                ),
+                step_cap,
+            )
             pending.append((p, ch))
             if len(pending) >= _DRAIN_WINDOW:
                 drain(pending)
@@ -645,36 +690,43 @@ class SerialBackend(ExecutionBackend):
         one blocking ``int(count)`` host sync per chunk plus one per
         capacity retry, the capacity bucket reset every superstep, children
         forced through ``np.asarray`` per chunk."""
-        g, expand_fn, config = self.g, self._expand_fn, self.config
+        config = self.config
         cap = max(config.initial_capacity, 1)
         for w in waves:
             for lo in range(0, len(w), config.chunk_size):
-                chunk = np.asarray(w[lo : lo + config.chunk_size])
-                cb = int(chunk.shape[0])
-                bucket = min(config.chunk_size, next_pow2(max(cb, 1)))
-                pad = bucket - cb
-                if pad:
-                    chunk = np.concatenate(
-                        [chunk, np.full((pad, size), -1, np.int32)], axis=0
+                with obs.span("expand.plan"):
+                    chunk = np.asarray(w[lo : lo + config.chunk_size])
+                    cb = int(chunk.shape[0])
+                    bucket = min(config.chunk_size, next_pow2(max(cb, 1)))
+                    pad = bucket - cb
+                    if pad:
+                        chunk = np.concatenate(
+                            [chunk, np.full((pad, size), -1, np.int32)],
+                            axis=0,
+                        )
+                    n_valid = jnp.concatenate(
+                        [jnp.full((cb,), size, jnp.int32),
+                         jnp.zeros((pad,), jnp.int32)]
                     )
-                n_valid = jnp.concatenate(
-                    [jnp.full((cb,), size, jnp.int32),
-                     jnp.zeros((pad,), jnp.int32)]
-                )
-                chunk = jnp.asarray(chunk)
+                    chunk = jnp.asarray(chunk)
                 obs.count(st, "n_chunks", 1)
                 while True:
-                    self._signatures.add((size, bucket, cap))
-                    out = expand_fn(g, chunk, n_valid, out_cap=cap)
+                    out = self._dispatch(st, size, bucket, chunk, n_valid, cap)
                     children, count = out[0], out[1]
                     ngen, ncanon = out[-2], out[-1]
-                    count = int(count)
+                    with obs.span("expand.sync"):
+                        count = int(obs.to_host(count))
                     obs.count(st, "n_host_syncs", 1)
                     if count <= cap:
                         break
                     programs.retire(children)
                     cap = next_pow2(count)
-                obs.count(st, "n_generated", int(ngen))
-                obs.count(st, "n_canonical", int(ncanon))
+                with obs.span("expand.sync"):
+                    ngen = int(obs.to_host(ngen))
+                    ncanon = int(obs.to_host(ncanon))
+                obs.count(st, "n_generated", ngen)
+                obs.count(st, "n_canonical", ncanon)
                 if count:
-                    store.append(np.asarray(children[:count]))
+                    rows = self._fetch(st, children, count)
+                    with obs.span("expand.fold", rows=count):
+                        store.append(rows)

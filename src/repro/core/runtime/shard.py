@@ -793,8 +793,8 @@ class ShardMapBackend(ExecutionBackend):
         if placement == "host_async":
             # overlap: joined by the loop at the seal boundary; eligibility
             # guarantees neither alpha_rows nor the domain scatter fires
-            obs.annotate("canonicalize_submit")
-            pending = aggregation.submit_level2(uniq, counts_q)
+            with obs.span("canonicalize.submit", n_quick=n):
+                pending = aggregation.submit_level2(uniq, counts_q)
             self._row_slot, self._row_cnts = row_slot, cnts
             self._agg_table, self._agg_global_cap = None, global_cap
             return pending, None

@@ -23,6 +23,19 @@ class StepStats:
     #: The PR-2 chunk loop pays one per chunk; the fused pipeline
     #: (DESIGN.md §8) drains all counts once — O(1) per superstep.
     n_host_syncs: int = 0
+    #: candidate lanes the chunk programs computed: the padded candidate
+    #: table of every dispatch (bucket rows x k x D in vertex mode,
+    #: x 2k x D in edge mode), capacity retries included since the chip
+    #: recomputes them. ``n_generated / n_lanes`` is the lane fill.
+    n_lanes: int = 0
+    #: every blocking device→host read of the serial backend (control
+    #: syncs, drains of rows, codes and aggregation tables): >=
+    #: ``n_host_syncs``.
+    n_device_reads: int = 0
+    #: bytes of child rows, carried codes and local-vertex tables drained
+    #: into the host store during expansion (the next frontier's trip
+    #: through the host); aggregation's drains are ``bytes_to_host``.
+    rows_to_host: int = 0
     frontier_bytes: int = 0          # raw embedding-list bytes (Fig 9 baseline)
     odag_bytes: int = 0              # ODAG-compressed bytes (Fig 9)
     collective_bytes: int = 0        # bytes exchanged in the distributed step

@@ -43,7 +43,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-from repro.core import canon_math
+from repro.core import canon_math, obs
 from repro.kernels.dispatch import pallas_call
 
 #: permutation-axis tile (the fori/grid step); 128 divides 8! and bounds
@@ -430,8 +430,8 @@ def canonicalize_on_device(codes_np, *, with_orbits: bool = False,
         jnp.asarray(padded), jnp.asarray(valid), nvs,
         with_orbits=with_orbits, use_kernel=use_kernel, interpret=interpret,
     )
-    return (np.asarray(canon[:m]), np.asarray(sigma[:m]),
-            np.asarray(rep[:m]))
+    return (obs.to_host(canon[:m]), obs.to_host(sigma[:m]),
+            obs.to_host(rep[:m]))
 
 
 def make_canon_fn(*, use_kernel: bool = False, interpret=None):

@@ -157,10 +157,11 @@ def resolve_canonical_placement(placement: Optional[str] = None) -> str:
 def device_scope(name: str):
     """Named XLA scope for a device-program stage (``repro/<name>``):
     the device-side half of the §12 span taxonomy. ``jax.named_scope``
-    only relabels operations during tracing — zero runtime cost — so the
-    fused-chunk / halo-exchange / aggregation-bin stages are ALWAYS
-    scoped, and a ``jax.profiler.trace`` capture lines its device slices
-    up with the host spans (``obs.annotate``) without recompiling."""
+    only relabels operations during tracing — zero runtime cost, and the
+    persistent compile cache's key ignores it — so the chunk program's
+    stages (``explore.fused_chunk_step``), the halo exchange and the
+    aggregation bin are ALWAYS scoped, and a ``jax.profiler`` capture
+    lines its device slices up with the host spans (``obs.span``)."""
     return jax.named_scope(f"repro/{name}")
 
 # NB: the old ``default_use_pallas`` static heuristic moved into the

@@ -7,8 +7,11 @@ the raw ``st.x += v`` arithmetic, traced or not), the Chrome trace-event
 export schema (every "X" event carries name/ph/ts/dur/pid/tid — the
 subset Perfetto needs), phase coverage of real runs on both backends,
 the ``trace=False`` zero-extra-syncs guard, the ``trace_sync`` probe
-timings (``t_gather``/``t_exchange``) on partitioned runs, and the
-summary/phase-wall additions to ``RunStats``.
+timings (``t_gather``/``t_exchange``) on partitioned runs, the
+summary/phase-wall additions to ``RunStats``, every span reaching a
+``jax.profiler`` capture (with the expansion/aggregation sub-spans nested
+under their phases in both chunk loops), the always-on ``n_lanes`` /
+``n_device_reads`` / ``rows_to_host`` counters, and ``obs.totals()``.
 
 Graphs stay ~40 vertices: every engine run here is sub-second.
 """
@@ -41,6 +44,7 @@ COUNTER_STATS = (
     "n_frontier", "n_children", "n_chunks", "n_host_syncs",
     "bytes_to_host", "collective_bytes", "n_generated", "n_canonical",
     "n_quick_patterns", "n_canonical_patterns", "n_iso_checks",
+    "n_lanes", "n_device_reads", "rows_to_host",
 )
 
 
@@ -414,3 +418,224 @@ def test_render_trace_cli(tmp_path, capsys):
     bad = tmp_path / "bad.trace.json"
     bad.write_text(json.dumps({"traceEvents": []}))
     assert render_trace.main(["--check", str(bad)]) == 1
+
+
+# ---------------------------------------------------------------------------
+# spans on the profiler's clock: TraceAnnotation per span
+# ---------------------------------------------------------------------------
+
+def test_span_opens_trace_annotation_only_while_traced(monkeypatch):
+    opened = []
+
+    class FakeAnnotation:
+        def __init__(self, name, **meta):
+            opened.append((name, meta))
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(tracer_lib, "TraceAnnotation", FakeAnnotation)
+    assert tracer_lib.current() is None
+    with obs.span("expand", step=2):
+        pass
+    assert opened == []                 # disabled: no profiler machinery
+    assert obs.span("a") is obs.span("b")   # still the shared no-op
+    tr = tracer_lib.Tracer()
+    tracer_lib.install(tr)
+    try:
+        with obs.span("superstep", step=2):
+            with obs.span("expand.fetch", rows=5):
+                pass
+        with obs.span("cost_model"):
+            pass
+    finally:
+        tracer_lib.install(None)
+    # children inherit the enclosing step; every span carries the run id
+    assert opened == [
+        ("superstep", {"run": tr.run_id, "step": 2}),
+        ("expand.fetch", {"run": tr.run_id, "step": 2}),
+        ("cost_model", {"run": tr.run_id}),
+    ]
+    assert tracer_lib.Tracer().run_id != tr.run_id
+
+
+def _profiled(tmp_path, work):
+    """Host events ``(name, stats)`` of a ``jax.profiler`` capture of
+    ``work()`` on the CPU."""
+    import os
+
+    import jax
+
+    d = str(tmp_path / "prof")
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(d, profiler_options=opts)
+    try:
+        work()
+    finally:
+        jax.profiler.stop_trace()
+    paths = [os.path.join(dp, f) for dp, _, fs in os.walk(d)
+             for f in fs if f.endswith(".xplane.pb")]
+    assert len(paths) == 1
+    prof = jax.profiler.ProfileData.from_file(paths[0])
+    return [(ev.name, dict(ev.stats)) for plane in prof.planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events]
+
+
+#: every program span of the expansion and aggregation taxonomy
+SUB_SPANS = (
+    "expand.plan", "expand.dispatch", "expand.sync", "expand.fetch",
+    "expand.fold", "aggregate.bin", "aggregate.drain", "aggregate.domains",
+    "canonicalize.submit",
+)
+
+
+def test_profiler_capture_holds_program_spans(tmp_path):
+    g = _graph()
+    configs = [
+        (MotifsApp(max_size=3), RunConfig(
+            trace=True, canonical_placement="host_async")),
+        (MotifsApp(max_size=3), RunConfig(trace=True, async_chunks=False)),
+        (FSMApp(support=3, max_size=3), RunConfig(trace=True)),
+    ]
+    runs = []
+
+    def work():
+        for app, cfg in configs:
+            rt = SuperstepRuntime(g, app, cfg)
+            rt.run()
+            runs.append(rt.observer.tracer.run_id)
+        # a span opened on another thread lands on that thread's line
+        tr = tracer_lib.Tracer()
+        tracer_lib.install(tr)
+        try:
+            t = threading.Thread(target=lambda: _enter(obs.span("level2")))
+            t.start()
+            t.join(timeout=60)
+            assert not t.is_alive()
+        finally:
+            tracer_lib.install(None)
+
+    events = _profiled(tmp_path, work)
+    names = {n for n, _ in events}
+    want = {"superstep", "materialize", "aggregate", "alpha", "expand",
+            "seal", "canonicalize", "level2", *SUB_SPANS}
+    assert want <= names, want - names
+    fetch = [s for n, s in events if n == "expand.fetch"]
+    assert {s["run"] for s in fetch} <= set(runs)
+    assert all("step" in s for s in fetch)
+
+
+def _enter(ctx):
+    with ctx:
+        pass
+
+
+@pytest.mark.parametrize("async_chunks", [True, False])
+@pytest.mark.parametrize("app_name", ["motifs", "fsm"])
+def test_sub_spans_nest_under_their_phases(app_name, async_chunks):
+    g = _graph()
+    rt = SuperstepRuntime(
+        g, APPS[app_name](), RunConfig(trace=True, async_chunks=async_chunks)
+    )
+    rt.run()
+    spans = rt.observer.tracer.spans
+    seen = set()
+    for sp in spans:
+        head = sp.name.split(".")[0]
+        if sp.name in SUB_SPANS:
+            seen.add(sp.name)
+            parent = "aggregate" if head == "canonicalize" else head
+            assert sp.parent == parent, (sp.name, sp.parent)
+    assert {"expand.plan", "expand.dispatch", "expand.sync",
+            "expand.fetch", "expand.fold", "aggregate.drain"} <= seen
+    if app_name == "fsm":
+        assert "aggregate.domains" in seen
+
+
+# ---------------------------------------------------------------------------
+# always-on counters: lanes, device reads, rows to host, process totals
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("async_chunks", [True, False])
+@pytest.mark.parametrize("app_name", ["motifs", "fsm", "cliques"])
+def test_lanes_count_every_dispatch(app_name, async_chunks):
+    g = _graph()
+    app = APPS[app_name]()
+    # capacity 1 forces retries: the chip computes those lanes again
+    rt = SuperstepRuntime(g, app, RunConfig(
+        async_chunks=async_chunks, initial_capacity=1, chunk_size=64))
+    backend = rt.backend
+    fn, lanes, calls = backend._expand_fn, [], []
+
+    def counted(g_, members, n_valid, out_cap):
+        c, k = members.shape
+        k = k if app.mode == "vertex" else 2 * k
+        lanes.append(c * k * g_.max_degree)
+        return fn(g_, members, n_valid, out_cap=out_cap)
+
+    backend._expand_fn = counted
+    res = rt.run()
+    steps = res.stats.steps
+    assert sum(s.n_lanes for s in steps) == sum(lanes) > 0
+    assert len(lanes) > sum(s.n_chunks for s in steps)     # retries seen
+    for s in steps:
+        assert s.n_generated <= s.n_lanes
+
+
+@pytest.mark.parametrize("async_chunks", [True, False])
+@pytest.mark.parametrize("app_name", ["motifs", "fsm", "cliques"])
+def test_device_reads_and_rows_to_host(app_name, async_chunks):
+    g = _graph()
+    rt = SuperstepRuntime(g, APPS[app_name](),
+                          RunConfig(async_chunks=async_chunks))
+    store, received = rt.store, []
+    append = store.append
+
+    def recorded(rows):
+        received.append(np.asarray(rows).nbytes)
+        return append(rows)
+
+    store.append = recorded
+    res = rt.run()
+    steps = res.stats.steps
+    # the first append is the loop's initial frontier, not an expansion
+    assert sum(s.rows_to_host for s in steps) == sum(received[1:]) > 0
+    for s in steps:
+        assert s.n_device_reads >= s.n_host_syncs
+    assert sum(s.n_device_reads for s in steps) > sum(
+        s.n_host_syncs for s in steps)
+
+
+def test_totals_grow_by_each_completed_run():
+    g = _graph()
+    for cfg in (RunConfig(), RunConfig(trace=True)):
+        before = obs.totals()
+        res = SuperstepRuntime(g, MotifsApp(max_size=3), cfg).run()
+        after = obs.totals()
+        steps = res.stats.steps
+        grew = {k: after[k] - before.get(k, 0) for k in after}
+        assert grew["runs"] == 1 and grew["supersteps"] == len(steps)
+        for k in ("n_generated", "n_lanes", "n_host_syncs",
+                  "n_device_reads", "rows_to_host", "bytes_to_host",
+                  "n_chunks", "n_children"):
+            assert grew[k] == sum(getattr(s, k) for s in steps), k
+        assert "step" not in after and "size" not in after
+        assert "t_expand" not in after            # floats are not counted
+    # a run that dies folds nothing
+    before = obs.totals()
+    from repro.core.runtime import FaultPlan, FaultSpec
+    with pytest.raises(Exception, match="injected"):
+        SuperstepRuntime(g, MotifsApp(max_size=3), RunConfig(
+            faults=FaultPlan([FaultSpec("expand", 2, "crash")]))).run()
+    assert obs.totals() == before
+
+
+def test_registry_keeps_counters_and_gauges_only():
+    reg = metrics_lib.MetricsRegistry()
+    assert not hasattr(reg, "observe") and not hasattr(reg, "dists")
+    assert set(reg.snapshot()) == {"counters", "gauges", "gauge_max"}
