@@ -20,13 +20,14 @@ class CliquesApp(MiningApp):
     collect_embeddings: bool = True
 
     def filter(self, g: DeviceGraph, members, n_valid, rows, cand):
-        """isClique: the new vertex must neighbour every existing member."""
-        k = members.shape[1]
-        pos = jnp.arange(k)[None, :]
-        m = members[rows]                       # (Ncand, k)
-        valid = pos < n_valid[rows][:, None]
-        adj = g.is_edge(m, cand[:, None])       # (Ncand, k)
-        return (adj | ~valid).all(axis=1)
+        """isClique: the new vertex must neighbour every existing member.
+        One (Ncand,) gather per member column: an (Ncand, k) gather would
+        pad k to a whole lane tile on the device."""
+        nv = n_valid[rows]
+        ok = jnp.ones(rows.shape, dtype=bool)
+        for i in range(members.shape[1]):
+            ok &= g.is_edge(members[:, i][rows], cand) | (i >= nv)
+        return ok
 
 
 def maximal_cliques(result, g: DeviceGraph):

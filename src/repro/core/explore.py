@@ -9,7 +9,9 @@ TPU adaptation (see DESIGN.md §2): instead of per-embedding adjacency-list
 walks, we materialise a dense padded candidate tensor ``(C, k, D)`` /
 ``(C, 2k, D)`` from the padded neighbour table and evaluate *all* pruning
 rules as fused mask expressions. The engine chunks the frontier so this
-tensor stays bounded.
+tensor stays bounded. The serial vertex path packs the same candidates
+without the padding past each member's degree
+(:func:`expand_vertex_packed`, DESIGN.md §8).
 
 :func:`fused_chunk_step` is the single device pass of the fused superstep
 pipeline (DESIGN.md §8): expansion + canonicality + app filter + stream
@@ -19,7 +21,7 @@ upload a wave twice or sync per chunk.
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -275,6 +277,82 @@ def expand_vertex(
     )
 
 
+def expand_vertex_packed(
+    g: DeviceGraph,
+    members: jnp.ndarray,   # (C, k) int32, pad -1
+    n_valid: jnp.ndarray,   # (C,) int32
+    n_lanes: int,
+    *,
+    use_pallas: bool = False,
+    interpret=None,
+) -> Expansion:
+    """:func:`expand_vertex` over packed candidate lanes (DESIGN.md §8).
+
+    Each valid member ``i`` of row ``c`` owns a segment of ``deg(member)``
+    consecutive lanes, segments in row-major ``(c, i)`` order, so lane
+    ``(c, i, j)`` is the padded slot ``(c, i, j)`` with the padding past
+    each member's degree removed: the kept candidates, their order, and
+    both counts equal the padded path's. ``n_lanes`` (static) must hold
+    the chunk's degree sum; lanes past it are invalid.
+
+    A non-empty segment marks its first lane with its own index and a
+    running max carries that index across its lanes — a scan over the
+    lanes in place of a per-lane search over the segment starts. The
+    first-occurrence dedup tests the ``k`` members per lane, one member
+    column at a time, replacing the padded ``(C, k, k, D)`` intermediate."""
+    with device_scope("gather"):
+        c, k = members.shape
+        pos = jnp.arange(k, dtype=jnp.int32)
+        member_ok = pos[None, :] < n_valid[:, None]              # (C, k)
+        flat_members = jnp.maximum(members, 0).reshape(c * k)
+        seg_len = jnp.where(
+            member_ok.reshape(c * k), g.deg[flat_members], 0
+        ).astype(jnp.int32)
+        seg_start = jnp.cumsum(seg_len) - seg_len                # exclusive
+        lane = jnp.arange(n_lanes, dtype=jnp.int32)
+        mark = jnp.zeros((n_lanes,), jnp.int32).at[
+            jnp.where(seg_len > 0, seg_start, n_lanes)
+        ].max(jnp.arange(c * k, dtype=jnp.int32), mode="drop")
+        seg = jax.lax.cummax(mark)
+        rows = seg // k
+        slot = seg % k
+        cand = jnp.where(
+            lane < seg_len.sum(),
+            g.nbr[flat_members[seg], lane - seg_start[seg]],
+            -1,
+        )                                                        # (L,)
+        # the parents' members column by column: (L,) gathers keep the
+        # lanes minor, where an (L, k) gather pads k to a whole lane tile
+        cols = [members[:, i][rows] for i in range(k)]
+        parent_nv = n_valid[rows]
+        # valid members before this lane's own: i' < min(i, n_valid)
+        before = jnp.minimum(slot, parent_nv)
+        is_member = jnp.zeros((n_lanes,), bool)
+        seen_earlier = jnp.zeros((n_lanes,), bool)
+        for i, col in enumerate(cols):
+            is_member |= col == cand
+            seen_earlier |= (i < before) & g.is_edge(col, cand)
+        valid = (cand >= 0) & ~is_member & ~seen_earlier
+        parents = jnp.stack(cols, axis=1)                        # (L, k)
+
+    with device_scope("canonical_check"):
+        if use_pallas:
+            canon = cc_ops.canonical_check(
+                g, parents, parent_nv, cand,
+                mode="vertex", interpret=interpret,
+            )
+        else:
+            canon = canonical.vertex_check(g, parents, parent_nv, cand)
+    keep = valid & canon
+    return Expansion(
+        rows=rows,
+        cand=cand,
+        keep=keep,
+        n_generated=valid.sum().astype(jnp.int32),
+        n_canonical=keep.sum().astype(jnp.int32),
+    )
+
+
 def _expand_vertex_fused(g, members, n_valid, interpret=None) -> Expansion:
     """Vertex expansion through the fused ``expand_canonical`` kernel:
     validity + dedup + Alg.-2 in one VMEM pass, flattened to the same
@@ -463,6 +541,7 @@ def fused_chunk_step(
     compact_kernel: bool = False,
     aggregate_kernel: bool = False,
     aggregate_bin: str = "sort",
+    n_lanes: Optional[int] = None,
     interpret=None,
 ):
     """ONE device pass of the fused superstep pipeline (DESIGN.md §8):
@@ -500,6 +579,11 @@ def fused_chunk_step(
     is unchanged. A pre-built ``TileView`` is also accepted (the shard-map
     worker builds its own view with collectives).
 
+    ``n_lanes`` (static, vertex mode on a :class:`DeviceGraph` without
+    ``fused``) expands over that many packed candidate lanes
+    (:func:`expand_vertex_packed`) instead of the padded ``(C, k, D)``
+    table; the outputs are the same.
+
     Every stage runs under its own ``device_scope`` (``tile_gather``,
     ``gather``, ``canonical_check`` or the fused ``expand_canonical``,
     ``app_filter``, ``compact``, ``pack``), so a profiler trace ties each
@@ -511,7 +595,12 @@ def fused_chunk_step(
                 use_pallas=use_pallas, compact_kernel=compact_kernel,
                 interpret=interpret,
             )
-    if mode == "vertex":
+    if n_lanes is not None:
+        exp = expand_vertex_packed(
+            g, members, n_valid, n_lanes,
+            use_pallas=use_pallas, interpret=interpret,
+        )
+    elif mode == "vertex":
         exp = expand_vertex(
             g, members, n_valid,
             use_pallas=use_pallas, fused=fused, interpret=interpret,

@@ -46,7 +46,10 @@ def _static_native() -> bool:
 class RunConfig:
     """Configuration of one mining run, backend-agnostic (DESIGN.md §9)."""
 
-    chunk_size: int = 4096        # frontier rows per expansion program (serial)
+    #: serial chunk bound: frontier rows per expansion program, or, where
+    #: candidate lanes are packed (vertex mode, DESIGN.md §8), a lane
+    #: budget of ``chunk_size * k * D`` per program (k members, max degree D)
+    chunk_size: int = 4096
     initial_capacity: int = 4096  # starting output-capacity bucket (per shard
                                   # in the distributed backend)
     max_steps: int = 16           # hard cap on exploration depth

@@ -10,7 +10,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Dict, Optional
+from typing import Dict, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -51,8 +51,9 @@ def make_expand_fn(app: MiningApp, mode: str, use_pallas: bool = False,
     """Jitted chunk program of the superstep pipeline: expand + canonicality
     + app filter + compaction (+ child quick patterns when the pipeline is
     fused, or the binned per-chunk level-1 partial with ``with_aggregates``
-    — DESIGN.md §10). Recompiled per (width, capacity) pow2 bucket; cached
-    across runs for hashable app configs."""
+    — DESIGN.md §10). Recompiled per (width, capacity) pow2 bucket, and per
+    lane bucket ``n_lanes`` on the packed path (:func:`plan_chunks`);
+    cached across runs for hashable app configs."""
     app_key = app_cache_key(app)
     key = None
     if app_key is not None:
@@ -63,8 +64,8 @@ def make_expand_fn(app: MiningApp, mode: str, use_pallas: bool = False,
         if cached is not None:
             return cached
 
-    @functools.partial(jax.jit, static_argnames=("out_cap",))
-    def fn(g: DeviceGraph, members, n_valid, out_cap: int):
+    @functools.partial(jax.jit, static_argnames=("out_cap", "n_lanes"))
+    def fn(g: DeviceGraph, members, n_valid, out_cap: int, n_lanes=None):
         return explore.fused_chunk_step(
             g, members, n_valid, out_cap,
             mode=mode,
@@ -78,6 +79,7 @@ def make_expand_fn(app: MiningApp, mode: str, use_pallas: bool = False,
             compact_kernel=compact_kernel,
             aggregate_kernel=aggregate_kernel,
             aggregate_bin=aggregate_bin,
+            n_lanes=n_lanes,
             interpret=interpret,
         )
 
@@ -140,20 +142,72 @@ def retire(*buffers) -> None:
                 pass
 
 
-def iter_chunks(waves, wave_dev, chunk_size: int, size: int):
-    """Yield device-sliced, pow2-padded chunks over all waves, uploading
-    each wave at most once (reusing the aggregation pass's upload)."""
+class ChunkPlan(NamedTuple):
+    """One chunk of a wave: rows ``[lo, lo + rows)`` padded to ``bucket``
+    rows. ``lanes`` is the candidate lanes the chunk program computes and
+    ``used`` how many of them hold a member's neighbour slot; ``packed``
+    says whether the program takes ``lanes`` as its packed lane bucket."""
+
+    lo: int
+    rows: int
+    bucket: int
+    lanes: int
+    used: int
+    packed: bool
+
+
+def plan_chunks(wave: np.ndarray, chunk_size: int, member_lanes: int,
+                deg: Optional[np.ndarray] = None):
+    """Cut a host wave of ``(N, k)`` rows into consecutive chunks.
+    ``member_lanes`` is the padded lanes of one member: D in vertex mode,
+    2·D in edge mode (two endpoint slots).
+
+    Padded (``deg`` None): ``chunk_size`` rows each, padded to a pow2 row
+    bucket, ``bucket * k * member_lanes`` lanes. Packed (``deg``, the host
+    degree array, vertex mode, DESIGN.md §8): a row needs
+    ``sum(deg(members))`` lanes, and a chunk takes rows in order while
+    they fit the lane budget ``chunk_size * k * D`` — a full padded
+    chunk's lanes — so the budget, not ``chunk_size``, sets its rows. Rows
+    and lanes are padded to pow2 buckets (the lanes capped at the budget),
+    so a size compiles O(log^2) programs. A row costs at least one lane of
+    the budget, which bounds a chunk's rows too."""
+    n, k = wave.shape
+    if deg is None:
+        lanes_per_row = k * member_lanes
+        for lo in range(0, n, chunk_size):
+            cb = min(chunk_size, n - lo)
+            bucket = min(chunk_size, next_pow2(max(cb, 1)))
+            yield ChunkPlan(lo, cb, bucket, bucket * lanes_per_row,
+                            cb * lanes_per_row, False)
+        return
+    budget = chunk_size * k * member_lanes
+    need = deg[wave].sum(axis=1, dtype=np.int64)
+    ends = np.cumsum(np.maximum(need, 1))
+    lanes = np.cumsum(need)
+    lo = 0
+    while lo < n:
+        base = ends[lo - 1] if lo else 0
+        hi = int(np.searchsorted(ends, base + budget, side="right"))
+        used = int(lanes[hi - 1] - (lanes[lo - 1] if lo else 0))
+        yield ChunkPlan(lo, hi - lo, next_pow2(hi - lo),
+                        min(budget, next_pow2(max(used, 1))), used, True)
+        lo = hi
+
+
+def iter_chunks(waves, wave_dev, size: int, plan):
+    """Yield ``(wave index, ChunkPlan, chunk, n_valid)`` over all waves:
+    each wave cut by ``plan`` (host wave -> its chunk plans),
+    uploaded at most once (reusing the aggregation pass's upload), and
+    sliced and padded on device."""
     for wi, w in enumerate(waves):
         if not len(w):
             continue
         if wave_dev[wi] is None:
             wave_dev[wi] = jnp.asarray(np.ascontiguousarray(w))
         wd = wave_dev[wi]
-        for lo in range(0, len(w), chunk_size):
-            cb = min(chunk_size, len(w) - lo)
-            bucket = min(chunk_size, next_pow2(max(cb, 1)))
-            chunk, n_valid = device_chunk(wd, lo, cb, bucket, size)
-            yield wi, lo, cb, bucket, chunk, n_valid
+        for p in plan(w):
+            chunk, n_valid = device_chunk(wd, p.lo, p.rows, p.bucket, size)
+            yield wi, p, chunk, n_valid
 
 
 def store_app_filter(app: MiningApp, g: DeviceGraph):

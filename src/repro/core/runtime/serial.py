@@ -24,6 +24,7 @@ the host reference path (``aggregation.aggregate_rows``).
 from __future__ import annotations
 
 import functools
+import math
 import time
 from typing import List, Optional
 
@@ -34,7 +35,7 @@ import numpy as np
 from repro.core import aggregation, explore, obs, pattern as pattern_lib
 from repro.kernels import canonical_refine
 from repro.core.api import MiningApp
-from repro.core.graph import PartitionedGraph
+from repro.core.graph import DeviceGraph, PartitionedGraph
 from repro.core.runtime import faults as faults_lib
 from repro.core.runtime import programs
 from repro.core.runtime.backend import ExecutionBackend
@@ -130,6 +131,18 @@ class SerialBackend(ExecutionBackend):
         )
         self._cache_before = programs.jit_cache_size(self._expand_fn)
         self._signatures = set()
+        # packed candidate lanes (DESIGN.md §8): vertex mode on the
+        # replicated graph, where the planner can read member degrees; the
+        # partitioned tile view and the fused kernel keep the padded table
+        self._packed = (
+            app.mode == "vertex"
+            and type(self.g) is DeviceGraph
+            and not config.fused_expand
+        )
+        self._deg = None            # host degrees, read at the first expand
+        #: children per used lane of the last chunk whose count was read:
+        #: sizes the next superstep's first output capacity (_first_cap)
+        self._child_rate = None
         self._lvl1 = None
         self._table = None
         self._gather_probe = (
@@ -446,6 +459,8 @@ class SerialBackend(ExecutionBackend):
             if blocks is self._waves
             else [None] * len(blocks)
         )
+        if self._packed and self._deg is None:
+            self._deg = obs.to_host(self.g.deg)
         carried = None
         if config.async_chunks:
             #: the NEXT superstep's level-1 state, folded from the chunk
@@ -537,15 +552,40 @@ class SerialBackend(ExecutionBackend):
         if "agg" in p:
             programs.retire(*p["agg"][:2])
 
-    def _dispatch(self, st, size, bucket, chunk, n_valid, cap):
+    def _plan(self, wave):
+        """The chunk plans of one host wave (``programs.plan_chunks``):
+        packed lanes when ``self._packed``; edge mode's padded lanes count
+        its 2·k endpoint slots."""
+        d = self.g.max_degree * (1 if self.app.mode == "vertex" else 2)
+        return programs.plan_chunks(
+            wave, self.config.chunk_size, d,
+            self._deg if self._packed else None,
+        )
+
+    def _first_cap(self, cap: int, plan) -> int:
+        """Output capacity for a superstep's first packed chunk: at least
+        the last read children-per-used-lane rate times this chunk's used
+        lanes, so a step of one or two large packed chunks does not compute
+        its first chunk twice for a capacity carried from a smaller step.
+        The padded path keeps its capacity as it was."""
+        if not plan.packed or self._child_rate is None:
+            return cap
+        want = math.ceil(self._child_rate * plan.used)
+        return max(cap, next_pow2(max(want, 1)))
+
+    def _note_rate(self, count: int, plan) -> None:
+        if plan.used:
+            self._child_rate = count / plan.used
+
+    def _dispatch(self, st, size, plan, chunk, n_valid, cap):
         """One chunk-program call at output capacity ``cap``: records its
-        signature and counts its candidate lanes, the padded (bucket, k, D)
-        table the chip computes (k = 2·size endpoint slots in edge mode)."""
-        self._signatures.add((size, bucket, cap))
-        k = size if self.app.mode == "vertex" else 2 * size
-        obs.count(st, "n_lanes", bucket * k * self.g.max_degree)
-        with obs.span("expand.dispatch", rows=bucket, cap=cap):
-            return self._expand_fn(self.g, chunk, n_valid, out_cap=cap)
+        signature and counts the candidate lanes the chip computes, the
+        plan's packed lane bucket or padded (bucket, k, D) table."""
+        self._signatures.add((size, plan.bucket, plan.lanes, cap))
+        obs.count(st, "n_lanes", plan.lanes)
+        kw = {"n_lanes": plan.lanes} if plan.packed else {}
+        with obs.span("expand.dispatch", rows=plan.bucket, cap=cap):
+            return self._expand_fn(self.g, chunk, n_valid, out_cap=cap, **kw)
 
     @staticmethod
     def _fetch(st, x, cnt: int, dtype=None) -> np.ndarray:
@@ -575,7 +615,7 @@ class SerialBackend(ExecutionBackend):
         with_patterns, with_aggregates = self.with_patterns, self.with_aggregates
         with obs.span("expand.plan"):
             chunks = list(
-                programs.iter_chunks(waves, wave_dev, config.chunk_size, size)
+                programs.iter_chunks(waves, wave_dev, size, self._plan)
             )
         obs.count(st, "n_chunks", len(chunks))
         if not chunks:
@@ -588,26 +628,29 @@ class SerialBackend(ExecutionBackend):
             for ch in chunks:
                 obs.count(
                     st, "t_gather",
-                    obs.probe_time(self._gather_probe, g, ch[4], ch[5]),
+                    obs.probe_time(self._gather_probe, g, ch[2], ch[3]),
                 )
 
         # ---- pilot: sync 1 calibrates the capacity bucket for the step --
-        _, _, cb0, bucket0, chunk0, n_valid0 = chunks[0]
+        _, plan0, chunk0, n_valid0 = chunks[0]
+        cap = self._first_cap(cap, plan0)
         out = self._rec(
-            self._dispatch(st, size, bucket0, chunk0, n_valid0, cap), cap
+            self._dispatch(st, size, plan0, chunk0, n_valid0, cap), cap
         )
         with obs.span("expand.sync"):
             c0 = int(obs.to_host(out["count"]))
         obs.count(st, "n_host_syncs", 1)
+        self._note_rate(c0, plan0)
         if c0 > cap:
             self._retire_outputs(out)
             cap = next_pow2(c0)
             out = self._rec(                       # count known exact
-                self._dispatch(st, size, bucket0, chunk0, n_valid0, cap), cap
+                self._dispatch(st, size, plan0, chunk0, n_valid0, cap), cap
             )
-        # scale the pilot count to a full bucket for the remaining chunks; a
-        # chunk that still overshoots is re-dispatched individually below
-        est = -((-c0 * bucket0) // max(cb0, 1))        # ceil(c0 * bucket0 / cb0)
+        # scale the pilot count from the lanes it used to all its lanes —
+        # a full chunk's — for the remaining chunks; a chunk that still
+        # overshoots is re-dispatched individually below
+        est = -((-c0 * plan0.lanes) // max(plan0.used, 1))
         step_cap = max(next_pow2(max(est, 1)), 64)
 
         codes_parts, lv_parts = [], []
@@ -632,13 +675,13 @@ class SerialBackend(ExecutionBackend):
                 self._retire_outputs(p)             # oversubscribed outputs
                 retry_cap = next_pow2(int(counts[i]))
                 p2 = self._rec(
-                    self._dispatch(st, size, ch[3], ch[4], ch[5], retry_cap),
+                    self._dispatch(st, size, *ch[1:], retry_cap),
                     retry_cap,
                 )
                 pending[i] = (p2, ch)
             for i, (p, ch) in enumerate(pending):
                 cnt = int(counts[i])
-                programs.retire(ch[4], ch[5])       # chunk inputs are dead now
+                programs.retire(ch[2], ch[3])       # chunk inputs are dead now
                 if cnt:
                     rows = self._fetch(st, p["children"], cnt, np.int32)
                     if with_patterns:
@@ -661,13 +704,7 @@ class SerialBackend(ExecutionBackend):
 
         pending = [(out, chunks[0])]
         for ch in chunks[1:]:
-            _, _, _, bucket_i, chunk_i, n_valid_i = ch
-            p = self._rec(
-                self._dispatch(
-                    st, size, bucket_i, chunk_i, n_valid_i, step_cap
-                ),
-                step_cap,
-            )
+            p = self._rec(self._dispatch(st, size, *ch[1:], step_cap), step_cap)
             pending.append((p, ch))
             if len(pending) >= _DRAIN_WINDOW:
                 drain(pending)
@@ -684,7 +721,8 @@ class SerialBackend(ExecutionBackend):
     # -- the PR-2 chunk loop, preserved as the measured baseline -----------
     def _expand_legacy(self, store, waves, size, st):
         """The PR-2 chunk loop, preserved bit-for-bit
-        (``benchmarks/bench_superstep.py``): every chunk is sliced and
+        (``benchmarks/bench_superstep.py``) but for the chunks, which the
+        fused loop's planner (``_plan``) cuts: every chunk is sliced and
         padded on the host and re-uploaded (even when aggregation already
         uploaded the wave — the double upload the fused pipeline removes),
         one blocking ``int(count)`` host sync per chunk plus one per
@@ -692,13 +730,15 @@ class SerialBackend(ExecutionBackend):
         forced through ``np.asarray`` per chunk."""
         config = self.config
         cap = max(config.initial_capacity, 1)
+        first = True
         for w in waves:
-            for lo in range(0, len(w), config.chunk_size):
+            with obs.span("expand.plan"):
+                plans = list(self._plan(w))
+            for plan in plans:
                 with obs.span("expand.plan"):
-                    chunk = np.asarray(w[lo : lo + config.chunk_size])
-                    cb = int(chunk.shape[0])
-                    bucket = min(config.chunk_size, next_pow2(max(cb, 1)))
-                    pad = bucket - cb
+                    cb = plan.rows
+                    chunk = np.asarray(w[plan.lo : plan.lo + cb])
+                    pad = plan.bucket - cb
                     if pad:
                         chunk = np.concatenate(
                             [chunk, np.full((pad, size), -1, np.int32)],
@@ -710,14 +750,17 @@ class SerialBackend(ExecutionBackend):
                     )
                     chunk = jnp.asarray(chunk)
                 obs.count(st, "n_chunks", 1)
+                if first:
+                    cap, first = self._first_cap(cap, plan), False
                 while True:
-                    out = self._dispatch(st, size, bucket, chunk, n_valid, cap)
+                    out = self._dispatch(st, size, plan, chunk, n_valid, cap)
                     children, count = out[0], out[1]
                     ngen, ncanon = out[-2], out[-1]
                     with obs.span("expand.sync"):
                         count = int(obs.to_host(count))
                     obs.count(st, "n_host_syncs", 1)
                     if count <= cap:
+                        self._note_rate(count, plan)
                         break
                     programs.retire(children)
                     cap = next_pow2(count)

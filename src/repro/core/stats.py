@@ -23,10 +23,11 @@ class StepStats:
     #: The PR-2 chunk loop pays one per chunk; the fused pipeline
     #: (DESIGN.md §8) drains all counts once — O(1) per superstep.
     n_host_syncs: int = 0
-    #: candidate lanes the chunk programs computed: the padded candidate
-    #: table of every dispatch (bucket rows x k x D in vertex mode,
-    #: x 2k x D in edge mode), capacity retries included since the chip
-    #: recomputes them. ``n_generated / n_lanes`` is the lane fill.
+    #: candidate lanes the chunk programs computed: the packed lane
+    #: bucket of every dispatch in the serial vertex path (DESIGN.md §8),
+    #: else the padded candidate table (bucket rows x k x D in vertex
+    #: mode, x 2k x D in edge mode), capacity retries included since the
+    #: chip recomputes them. ``n_generated / n_lanes`` is the lane fill.
     n_lanes: int = 0
     #: every blocking device→host read of the serial backend (control
     #: syncs, drains of rows, codes and aggregation tables): >=

@@ -156,11 +156,14 @@ def test_fused_syncs_are_constant_per_step():
     """The tentpole property: host control syncs per superstep are O(1) in
     the fused pipeline vs O(chunks) in the PR-2 loop."""
     g = G.random_labeled(40, 120, n_labels=2, seed=11)
+    # packed chunks take rows up to a lane budget of chunk_size * k * D:
+    # 8 rows' worth cuts this graph's frontiers into several chunks
+    cfg = dict(SMALL, chunk_size=8)
     legacy = run(
-        g, MotifsApp(max_size=3), EngineConfig(async_chunks=False, **SMALL)
+        g, MotifsApp(max_size=3), EngineConfig(async_chunks=False, **cfg)
     )
     fused = run(
-        g, MotifsApp(max_size=3), EngineConfig(async_chunks=True, **SMALL)
+        g, MotifsApp(max_size=3), EngineConfig(async_chunks=True, **cfg)
     )
     assert legacy.patterns == fused.patterns
     for st in fused.stats.steps:
@@ -203,13 +206,22 @@ def test_pow2_bucketing_bounds_compiles():
     )
     sigs = res.stats.chunk_signatures
     assert sigs, "no chunk programs dispatched"
-    for _, width, cap in sigs:
+    for size, width, lanes, cap in sigs:
         assert width & (width - 1) == 0, f"non-pow2 chunk width {width}"
         assert cap & (cap - 1) == 0, f"non-pow2 capacity {cap}"
+        # packed lane buckets: pow2, or the lane budget of a full chunk
+        budget = SMALL["chunk_size"] * size * int(g.degrees().max())
+        assert lanes & (lanes - 1) == 0 or lanes == budget, lanes
     assert res.stats.n_compiles <= len(sigs)
-    # the signature space itself is logarithmic: widths and caps are pow2
-    # buckets, so a frontier of any size compiles O(log) programs per size
+    # the signature space itself is logarithmic: widths, lane buckets and
+    # caps are pow2 buckets, so a frontier of any size compiles O(log)
+    # widths and lane buckets per size
     assert len(sigs) <= 4 * len(res.stats.steps) + 4
+    for size in {s[0] for s in sigs}:
+        mine = [s for s in sigs if s[0] == size]
+        widths, lane_buckets = {s[1] for s in mine}, {s[2] for s in mine}
+        assert len(widths) <= max(widths).bit_length()
+        assert len(lane_buckets) <= max(lane_buckets).bit_length() + 1
 
 
 def test_chunk_program_cache_reused_across_runs():

@@ -572,11 +572,15 @@ def test_lanes_count_every_dispatch(app_name, async_chunks):
     backend = rt.backend
     fn, lanes, calls = backend._expand_fn, [], []
 
-    def counted(g_, members, n_valid, out_cap):
+    def counted(g_, members, n_valid, out_cap, n_lanes=None):
+        # the packed lane bucket the program computes, else its padded
+        # (c, k, D) table (k = 2·size endpoint slots in edge mode)
         c, k = members.shape
         k = k if app.mode == "vertex" else 2 * k
-        lanes.append(c * k * g_.max_degree)
-        return fn(g_, members, n_valid, out_cap=out_cap)
+        lanes.append(n_lanes if n_lanes is not None
+                     else c * k * g_.max_degree)
+        kw = {} if n_lanes is None else {"n_lanes": n_lanes}
+        return fn(g_, members, n_valid, out_cap=out_cap, **kw)
 
     backend._expand_fn = counted
     res = rt.run()
